@@ -13,9 +13,11 @@ Two driver columns per cell (results in BENCH_SCALING.json):
     per_batch stays as the dispatch-overhead reference.
 
 The "device_scaling" section runs the shard_map engine (distr/sharded.py)
-at 1/2/4/8 forced host devices on a mixed insert+delete stream, one
-subprocess per device count (XLA's host-device count is process-global),
-against the single-host `run_stream` reference. HONEST CPU CAVEAT: forced
+at 1/2/4/8 devices on a mixed insert+delete stream against the single-host
+`run_stream` reference. On TPU every row runs in this process over
+`jax.devices()[:n]` (one process holds the chips); on the CPU each row is a
+subprocess with that many forced host devices (XLA's host-device count is
+process-global). HONEST CPU CAVEAT: forced
 host devices time-slice the same CPU cores, so this curve measures the
 collective/partition OVERHEAD of the explicit sharding, not parallel
 speedup — speedups < 1x are expected and recorded as-is; real scaling
@@ -75,84 +77,110 @@ def _cell(bg: BenchGraph, batch: int, label: str) -> dict:
     return out
 
 
-# one subprocess per device count: jax fixes the host-device count at init
+def _measure(mode: str, n_shards: int, log2_n: int, n_edges: int,
+             n_batches: int, ins: int, dels: int, cap: int) -> dict:
+    """One device-scaling row, timed in this process: the single-host
+    `run_stream` reference or the sharded engine over the first `n_shards`
+    devices. Returns {"dt": seconds of the second (warm) stream,
+    "affected": walks re-walked}."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.common import build_graph
+    from repro.core import generate_corpus
+    from repro.core.corpus import WalkConfig
+    from repro.core.update import WalkEngine
+    from repro.data.streams import mixed_edge_stream
+
+    bg = BenchGraph(log2_n=log2_n, n_edges=n_edges)
+    cfg = WalkConfig(n_walks_per_vertex=2, length=10)
+    g = build_graph(bg)
+    store = generate_corpus(jax.random.PRNGKey(1), g, cfg)
+    i_s, i_d, d_s, d_d = mixed_edge_stream(
+        jax.random.PRNGKey(2), n_batches, ins, dels, bg.log2_n)
+    key = jax.random.PRNGKey(9)
+
+    if mode == "single_host":
+        def once():
+            eng = WalkEngine(graph=jax.tree.map(jnp.array, g),
+                             store=jax.tree.map(jnp.array, store), cfg=cfg,
+                             rewalk_capacity=cap, max_pending=8)
+            t0 = time.perf_counter()
+            eng.run_stream(key, i_s, i_d, d_s, d_d)
+            jax.block_until_ready(eng.state.store.code)
+            dt = time.perf_counter() - t0
+            assert not eng.mav_overflowed
+            return dt, int(eng.total_affected)
+    else:
+        import dataclasses
+
+        from repro.distr.sharded import (ShardSpec, shard_state,
+                                         sharded_run_stream)
+        assert jax.device_count() >= n_shards, jax.devices()
+        spec = ShardSpec.create(n_shards, bg.n, store.size, g.codes.shape[0],
+                                cap)
+        # skew safety: rmat hubs concentrate on one shard, so bound the
+        # per-shard MAV gather by T (never overflows) like the reference
+        spec = dataclasses.replace(spec, mav_capacity=store.size)
+        base = shard_state(g, store, spec, cap, max_pending=8)
+
+        def once():
+            stacked = jax.tree.map(jnp.array, base)  # runs donate their copy
+            t0 = time.perf_counter()
+            stacked, aff = sharded_run_stream(
+                stacked, key, i_s, i_d, d_s, d_d, cfg=cfg, spec=spec,
+                capacity=cap, max_pending=8)
+            jax.block_until_ready(stacked.store.code)
+            dt = time.perf_counter() - t0
+            assert not bool(stacked.overflow.any()), \
+                "sharded capacity overflow"
+            return dt, int(stacked.total_affected[0])
+
+    once()  # compile pass
+    dt, aff = once()
+    return {"dt": dt, "affected": aff}
+
+
+# CPU only: one subprocess per device count, since XLA fixes the forced
+# host-device count at initialization
 _DEVICE_SUB = r"""
-import json, sys, time
+import json, sys
 sys.path.insert(0, {root!r}); sys.path.insert(0, {src!r})
-import jax, jax.numpy as jnp
 import repro.core  # x64
-from repro.core import generate_corpus
-from repro.core.corpus import WalkConfig
-from repro.core.update import WalkEngine
-from repro.data.streams import mixed_edge_stream
-from benchmarks.common import BenchGraph, build_graph
-
-mode = {mode!r}
-n_shards = {n_shards}
-bg = BenchGraph(log2_n={log2_n}, n_edges={n_edges})
-cfg = WalkConfig(n_walks_per_vertex=2, length=10)
-cap = {cap}
-g = build_graph(bg)
-store = generate_corpus(jax.random.PRNGKey(1), g, cfg)
-i_s, i_d, d_s, d_d = mixed_edge_stream(
-    jax.random.PRNGKey(2), {n_batches}, {ins}, {dels}, bg.log2_n)
-key = jax.random.PRNGKey(9)
-
-if mode == "single_host":
-    def once():
-        eng = WalkEngine(graph=jax.tree.map(jnp.array, g),
-                         store=jax.tree.map(jnp.array, store), cfg=cfg,
-                         rewalk_capacity=cap, max_pending=8)
-        t0 = time.perf_counter()
-        eng.run_stream(key, i_s, i_d, d_s, d_d)
-        jax.block_until_ready(eng.state.store.code)
-        dt = time.perf_counter() - t0
-        assert not eng.mav_overflowed
-        return dt, int(eng.total_affected)
-else:
-    import dataclasses
-    from repro.distr.sharded import (ShardSpec, shard_state,
-                                     sharded_run_stream)
-    assert jax.device_count() >= n_shards, jax.devices()
-    spec = ShardSpec.create(n_shards, bg.n, store.size, g.codes.shape[0],
-                            cap)
-    # skew safety: rmat hubs concentrate on one shard, so bound the
-    # per-shard MAV gather by T (never overflows) like the reference
-    spec = dataclasses.replace(spec, mav_capacity=store.size)
-    base = shard_state(g, store, spec, cap, max_pending=8)
-
-    def once():
-        stacked = jax.tree.map(jnp.array, base)  # runs donate their copy
-        t0 = time.perf_counter()
-        stacked, aff = sharded_run_stream(
-            stacked, key, i_s, i_d, d_s, d_d, cfg=cfg, spec=spec,
-            capacity=cap, max_pending=8)
-        jax.block_until_ready(stacked.store.code)
-        dt = time.perf_counter() - t0
-        assert not bool(stacked.overflow.any()), "sharded capacity overflow"
-        return dt, int(stacked.total_affected[0])
-
-once()  # compile pass
-dt, aff = once()
-print(json.dumps({{"dt": dt, "affected": aff}}))
+from benchmarks.bench_scaling import _measure
+print(json.dumps(_measure({mode!r}, {n_shards}, **{workload!r})))
 """
 
 
+def _on_tpu() -> bool:
+    import jax
+    return jax.default_backend() == "tpu"
+
+
 def _device_row(mode: str, n_shards: int, workload: dict) -> dict:
-    code = _DEVICE_SUB.format(root=_ROOT, src=os.path.join(_ROOT, "src"),
-                              mode=mode, n_shards=n_shards, **workload)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                        f"{max(n_shards, 1)}")
-    # skip accelerator plugin discovery: its retry backoff can stall
-    # subprocesses for minutes on accelerator-free hosts
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, cwd=_ROOT, timeout=3600)
-    if res.returncode != 0:
-        raise RuntimeError(f"device-scaling subprocess failed "
-                           f"({mode}, {n_shards}):\n{res.stderr[-2000:]}")
-    out = json.loads(res.stdout.strip().splitlines()[-1])
+    if _on_tpu():
+        # one process holds the chips: rows run here over jax.devices()[:n]
+        out = _measure(mode, n_shards, **workload)
+    else:
+        code = _DEVICE_SUB.format(root=_ROOT, src=os.path.join(_ROOT, "src"),
+                                  mode=mode, n_shards=n_shards,
+                                  workload=workload)
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
+                            f"{max(n_shards, 1)}")
+        # the parent runs on the CPU, so the child does too (skipping
+        # accelerator plugin discovery, whose retry backoff can stall
+        # subprocesses for minutes on accelerator-free hosts)
+        env["JAX_PLATFORMS"] = "cpu"
+        res = subprocess.run([sys.executable, "-c", code],
+                             capture_output=True, text=True, env=env,
+                             cwd=_ROOT, timeout=3600)
+        if res.returncode != 0:
+            raise RuntimeError(f"device-scaling subprocess failed "
+                               f"({mode}, {n_shards}):\n{res.stderr[-2000:]}")
+        out = json.loads(res.stdout.strip().splitlines()[-1])
     n_batches = workload["n_batches"]
     return {"walks_per_s": round(out["affected"] / out["dt"], 1),
             "updates_per_s": round(n_batches / out["dt"], 2),
@@ -176,7 +204,11 @@ def device_scaling() -> dict:
     emit("device_scaling/single_host", ref["us_per_walk"],
          f"walks_per_s={ref['walks_per_s']:.0f}")
     rows["single_host"] = dict(ref, devices=1, driver="run_stream")
-    for s in (1, 2, 4, 8):
+    counts = (1, 2, 4, 8)
+    if _on_tpu():
+        import jax
+        counts = tuple(c for c in counts if c <= jax.device_count())
+    for s in counts:
         row = _device_row("sharded", s, workload)
         row["speedup_vs_single_host"] = round(
             row["walks_per_s"] / max(ref["walks_per_s"], 1e-9), 3)

@@ -55,6 +55,10 @@ def main() -> None:
                          "sentinel-only over already-produced BENCH files)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import (enable_compile_cache,
+                                            raise_compiler_stack)
+    raise_compiler_stack()
+    enable_compile_cache()
     if args.smoke:
         common.SMOKE = True
     if args.skip_benches:

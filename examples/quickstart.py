@@ -9,6 +9,11 @@ import jax.numpy as jnp
 from repro.core import StreamingGraph, WalkConfig, generate_corpus
 from repro.core.update import WalkEngine
 from repro.data.streams import rmat_edges
+from repro.launch.compile_cache import (enable_compile_cache,
+                                        raise_compiler_stack)
+
+raise_compiler_stack()
+enable_compile_cache()
 
 N_VERTICES = 1 << 10      # 1024-vertex RMAT graph
 LOG2_N = 10

@@ -126,7 +126,7 @@ def gather_touched_segments(store: WalkStore, touched_v, capacity: int):
     aff_len = jnp.where(touched_v, seg_len, 0)
     # prefix layout of gathered segments
     out_start = jnp.concatenate(
-        [jnp.zeros((1,), I32), jnp.cumsum(aff_len).astype(I32)])
+        [jnp.zeros((1,), I32), jnp.cumsum(aff_len, dtype=I32)])
     total = out_start[-1]
     # for each output slot, which vertex segment does it come from?
     slot_ids = jnp.arange(capacity, dtype=I32)

@@ -45,8 +45,9 @@ class Overlay(NamedTuple):
     skey: jax.Array    # uint64 [E]
     scode: jax.Array   # uint64 [E]
     sowner: jax.Array  # uint32 [E]
-    # owner-sorted pending entries (dead rows keyed past 2^32): segment reads
-    okey: jax.Array    # uint64 [E]
+    # owner-sorted pending entries (dead rows keyed past every vertex id):
+    # segment reads
+    okey: jax.Array    # uint32 [E]
     ocode: jax.Array   # uint64 [E]
     oepoch: jax.Array  # uint32 [E]
     oslot: jax.Array   # int32  [E]
@@ -60,7 +61,7 @@ class Overlay(NamedTuple):
         `pending` is a PendingBlocks (any leading shape; flattened here).
         Dead rows (epoch == PAD_EPOCH) can never match a live slot-epoch, so
         they need no masking in the point-lookup index; the owner index keys
-        them past the 2^32 vertex-id range instead.
+        them at 2^32 - 1, past every vertex id, instead.
         """
         return _build_jit(store, pending.owner.reshape(-1),
                           pending.code.reshape(-1),
@@ -142,8 +143,8 @@ class Overlay(NamedTuple):
         base walks_of segment read (serve/walk_queries.py combines the two).
         """
         vertices = jnp.asarray(vertices, U32)
-        lo = jnp.searchsorted(self.okey, vertices.astype(U64), side="left")
-        hi = jnp.searchsorted(self.okey, (vertices + 1).astype(U64),
+        lo = jnp.searchsorted(self.okey, vertices, side="left")
+        hi = jnp.searchsorted(self.okey, vertices + jnp.asarray(1, U32),
                               side="left")
         idx = lo[:, None] + jnp.arange(capacity, dtype=I32)[None]
         in_seg = idx < hi[:, None]
@@ -160,8 +161,9 @@ def _build_jit(store: WalkStore, owner, code, epoch, slot) -> Overlay:
     slot64 = jnp.clip(slot, 0, store.n_walks * store.length - 1).astype(U64)
     skey = (slot64 << _SHIFT) | epoch.astype(U64)
     order = jnp.argsort(skey)
-    dead = (epoch == PAD_EPOCH).astype(U64)
-    okey = owner.astype(U64) + (dead << _SHIFT)
+    # a 32-bit key: each 64-bit sort key costs the TPU compile minutes
+    okey = jnp.where(epoch == PAD_EPOCH, jnp.asarray(0xFFFFFFFF, U32),
+                     owner.astype(U32))
     oorder = jnp.argsort(okey)
     return Overlay(base=store,
                    skey=skey[order], scode=code[order], sowner=owner[order],

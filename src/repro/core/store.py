@@ -177,7 +177,8 @@ class WalkStore:
         want_epoch = self.slot_epoch[slot]
 
         if backend == "xla-ref":
-            return self._scan_ref(lo, hi, f, want_epoch)
+            return self._scan_ref(lo, hi, f, want_epoch,
+                                  unpair=pairing.szudzik_unpair_u64)
 
         c0 = lo // CHUNK
         if backend == "interpret":
@@ -224,9 +225,12 @@ class WalkStore:
         return (jnp.where(over, o_out, out).astype(U32),
                 jnp.where(over, o_found, found))
 
-    def _scan_ref(self, lo, hi, f, want_epoch):
-        """The "xla-ref" backend: scalar while-loop over the uncompressed
-        codes (the seed's original FINDNEXT; reference semantics)."""
+    def _scan_ref(self, lo, hi, f, want_epoch,
+                  unpair=pairing.szudzik_unpair):
+        """Scalar while-loop over the uncompressed codes (the seed's
+        original FINDNEXT; reference semantics). The "xla-ref" backend
+        passes the plain u64 unpair, so on TPU it shares no math with the
+        kernels; the kernels' over-cap fallback keeps the platform's form."""
 
         def scan_one(lo1, hi1, f1, we1):
             def cond(state):
@@ -236,7 +240,7 @@ class WalkStore:
             def body(state):
                 i, _, _ = state
                 c = self.code[jnp.clip(i, 0, self.size - 1)]
-                cf, cv = pairing.szudzik_unpair(c)
+                cf, cv = unpair(c)
                 ok = (cf == f1) & (self.epoch[jnp.clip(i, 0, self.size - 1)] == we1)
                 return (i + 1, ok, jnp.where(ok, cv.astype(U32), jnp.asarray(0, U32)))
 

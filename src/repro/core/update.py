@@ -830,7 +830,10 @@ def merge_interleave(base: WalkStore, acc_owner, acc_code, acc_epoch,
                                               n_walks * length - 1)])
 
     # sort the (small) accumulator by (owner, code); dead rows to the end
-    order_a = jnp.lexsort((acc_code, acc_owner, ~live_a))
+    # under an owner key no vertex id reaches (a two-key sort: each key of
+    # a TPU sort costs minutes of compile)
+    order_a = jnp.lexsort((acc_code, jnp.where(live_a, acc_owner,
+                                               jnp.asarray(0xFFFFFFFF, U32))))
     acc_owner = acc_owner[order_a]
     acc_code = acc_code[order_a]
     acc_epoch = acc_epoch[order_a]
@@ -847,7 +850,7 @@ def merge_interleave(base: WalkStore, acc_owner, acc_code, acc_epoch,
                              side="left")
     pos_a = jnp.where(live_a, pos_a, t)  # dead acc rows park at the end
 
-    live_prefix = jnp.cumsum(live_b.astype(I32))          # live base[<=i]
+    live_prefix = jnp.cumsum(live_b, dtype=I32)           # live base[<=i]
     # acc entries inserted before base[i] = those with pos <= i
     pos_sorted = jnp.sort(pos_a)
     acc_before = jnp.searchsorted(pos_sorted, jnp.arange(t, dtype=I32),

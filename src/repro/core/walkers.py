@@ -141,7 +141,7 @@ def rejection_fallback(key, graph, v, prev, overflow, nxt, p, q, n_trials,
                        side_rows: int | None = None):
     """Replace `nxt` on overflowed lanes with per-lane rejection samples.
 
-    Three-tier cond: no overflow -> identity trace; overflow count fits the
+    Three-tier switch: no overflow -> identity; overflow count fits the
     side-batch -> compact the overflowed lanes into `side_rows` lanes and
     scatter the samples back; otherwise re-run every lane. All tiers use
     `_node2vec_step_perlane`, whose draws depend only on (key, lane index),
@@ -166,13 +166,13 @@ def rejection_fallback(key, graph, v, prev, overflow, nxt, p, q, n_trials,
                                      lane_ids)
         return jnp.where(overflow, rej, nxt)
 
-    def with_fallback(_):
-        if side >= b:
-            return whole_batch(None)
-        return jax.lax.cond(n_over <= side, side_batch, whole_batch, None)
-
-    return jax.lax.cond(jnp.any(overflow), with_fallback, lambda _: nxt,
-                        None)
+    # one three-way switch (TPU's compiler aborts on the nested-cond form)
+    if side >= b:
+        branch = jnp.where(n_over > 0, 2, 0)
+    else:
+        branch = jnp.where(n_over > 0, jnp.where(n_over <= side, 1, 2), 0)
+    return jax.lax.switch(branch.astype(I32),
+                          (lambda _: nxt, side_batch, whole_batch), None)
 
 
 def _neighbor_window(graph, v, dmax: int):

@@ -22,9 +22,10 @@ Layout (shard k of S owns vertices [k*vps, (k+1)*vps)):
     combined MAV, so it is recomputed identically everywhere with no
     collective.
 
-Per `stream_step`, exactly two collectives:
-  1. MAV combine — one `lax.pmin` over the int64[n_walks] composite keys
-    (core/mav.py::keyed_pmin); (p, owner)-lexicographic keys make the
+Per `stream_step`, exactly two exchanges:
+  1. MAV combine — a min over the int64[n_walks] composite keys
+    (core/mav.py::keyed_pmin), run as two int32 `lax.pmin`s since TPUs
+    all-reduce 64-bit values only as sums; (p, owner)-lexicographic keys make the
     cross-shard tie-break identical to the single-host segment_min.
   2. walk handoff — one `lax.all_to_all` of fixed-size frontier-lane slabs
     per rewalk step (distr/handoff.py): a lane whose next vertex lives on
@@ -56,12 +57,12 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import pairing
 from repro.core.corpus import WalkConfig
-from repro.core.graph import SENTINEL, StreamingGraph, edge_code
+from repro.core.graph import (SENTINEL, StreamingGraph, drop_sorted,
+                              edge_code)
 from repro.core.mav import gather_touched_segments, keyed_pmin, mav_from_keyed
 from repro.core.store import PAD_EPOCH, WalkStore
 from repro.core.update import EngineState, PendingBlocks, VersionBlock
@@ -112,6 +113,19 @@ class ShardSpec:
                          slab=rewalk_capacity)
 
 
+def _pmin_keys(best):
+    """Cross-shard min of the int64 composite MAV keys `p * 2^32 + owner` as
+    two int32 collectives — TPUs all-reduce 64-bit values only as sums: the
+    min of the high words (p), then the min of the low words (owner) over
+    the shards holding that p. Equal to `lax.pmin(best)` for owner < 2^31."""
+    hi = (best >> 32).astype(I32)
+    lo = (best & jnp.asarray(0xFFFFFFFF, jnp.int64)).astype(I32)
+    hi_min = jax.lax.pmin(hi, AXIS)
+    lo = jnp.where(hi == hi_min, lo, jnp.iinfo(jnp.int32).max)
+    lo_min = jax.lax.pmin(lo, AXIS)
+    return (hi_min.astype(jnp.int64) << 32) | lo_min.astype(jnp.int64)
+
+
 # ------------------------------------------------------- local graph update
 
 
@@ -122,7 +136,7 @@ def _local_delete(codes, gone):
     pos = jnp.clip(jnp.searchsorted(gone, codes, side="left"), 0,
                    gone.shape[0] - 1)
     hit = gone[pos] == codes
-    return jnp.sort(jnp.where(hit, SENTINEL, codes))
+    return drop_sorted(codes, hit)
 
 
 def _local_insert(codes, new_masked, capacity: int):
@@ -131,7 +145,7 @@ def _local_insert(codes, new_masked, capacity: int):
     Returns (codes, overflow): overflow = live codes didn't fit."""
     merged = jnp.sort(jnp.concatenate([codes, new_masked]))
     dup = jnp.concatenate([jnp.asarray([False]), merged[1:] == merged[:-1]])
-    merged = jnp.sort(jnp.where(dup, SENTINEL, merged))
+    merged = drop_sorted(merged, dup)
     overflow = jnp.sum(merged != SENTINEL) > capacity
     return merged[:capacity], overflow
 
@@ -366,7 +380,7 @@ def _sharded_apply_update(state: EngineState, ins_src, ins_dst, del_src,
         jnp.concatenate([g_touched, p_touched]),
         jnp.concatenate([g_valid, p_valid]),
         store.length, store.n_walks)
-    mav = mav_from_keyed(jax.lax.pmin(best, AXIS), store.length)
+    mav = mav_from_keyed(_pmin_keys(best), store.length)
 
     rw = _sharded_rewalk(key, graph, store, mav, new_epoch, cfg, capacity,
                          spec, my_shard, with_obs=with_obs)
@@ -468,10 +482,10 @@ def make_sharded_stream_fn(mesh, cfg: WalkConfig, spec: ShardSpec,
         stacked = jax.tree.map(lambda leaf: leaf[None], state)
         return stacked, affected[None]
 
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(AXIS), P(), P(), P(), P(), P()),
-        out_specs=(P(AXIS), P(AXIS)), check_rep=False)
+        out_specs=(P(AXIS), P(AXIS)), check_vma=False)
 
 
 @lru_cache(maxsize=None)
@@ -520,10 +534,10 @@ def make_sharded_stream_obs_fn(mesh, cfg: WalkConfig, spec: ShardSpec,
         stacked_m = jax.tree.map(lambda leaf: leaf[None], metrics)
         return stacked, stacked_m, affected[None]
 
-    return shard_map(
+    return jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(), P(), P(), P(), P()),
-        out_specs=(P(AXIS), P(AXIS), P(AXIS)), check_rep=False)
+        out_specs=(P(AXIS), P(AXIS), P(AXIS)), check_vma=False)
 
 
 @lru_cache(maxsize=None)

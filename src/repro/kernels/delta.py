@@ -11,9 +11,11 @@ quantized bit width w ∈ {8, 16, 32, 64}:
 
 Decode kernel (the search hot path): branch-free unpack of all width classes
 + select, then a carry-correct 64-bit prefix sum built from two 16-bit-limb
-u32 cumsums. Encode is pure jnp (ops.py) — also 32-bit-native, so both
-directions run on TPU. Compression ratio matches the paper's DE study
-(benchmarks/bench_memory.py).
+u32 prefix sums. Both steps move data across lanes with rotations only
+(`pltpu.roll`), which Mosaic lowers and which XLA runs as `jnp.roll`, so the
+kernels and the XLA backends share one decode. Encode is pure jnp (ops.py)
+— also 32-bit-native, so both directions run on TPU. Compression ratio
+matches the paper's DE study (benchmarks/bench_memory.py).
 """
 from __future__ import annotations
 
@@ -23,36 +25,85 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.szudzik import _add64, _sub64
 
 U32 = jnp.uint32
+I32 = jnp.int32
 
 CHUNK = 128           # paper's b, aligned to the VPU lane count
 WORDS = 2 * CHUNK     # packed buffer words per chunk (w=64 raw worst case)
 ROWS = 8              # chunks per block
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _roll(x, shift: int):
+    """Lane rotation that lowers both in Mosaic and in XLA (pltpu.roll has
+    jnp.roll semantics; the shift is an int32 operand so no 64-bit value
+    reaches the kernel under x64). Jitted because the primitive has no
+    eager rule."""
+    return pltpu.roll(x, np.int32(shift), 1)
+
+
+def _shift_up(x, s: int, lane):
+    """y[:, i] = x[:, i - s] for i >= s, else 0, along the lane axis.
+
+    Built from two rotations and the rotated lane iota, so the result does
+    not depend on the rotation direction the backend implements."""
+    n = x.shape[1]
+    src = _roll(lane, s)
+    y = jnp.where(src == lane - s, _roll(x, s), _roll(x, n - s))
+    return jnp.where(lane >= s, y, jnp.zeros_like(y))
+
+
+def lane_cumsum(x, lane):
+    """Inclusive prefix sum along the lane axis: a log-step scan of lane
+    shifts. `lane` is the int32 lane iota of x's shape.
+    Exact as long as the running sums do not wrap x's dtype."""
+    s = 1
+    while s < x.shape[1]:
+        x = x + _shift_up(x, s, lane)
+        s *= 2
+    return x
+
+
+def _tile_lanes(x, lane, period: int):
+    """y[:, i] = x[:, i % period]: repeat the first `period` lanes across
+    the row. The rotations cover every multiple of `period`, so the union
+    is the same for either rotation direction."""
+    head = jnp.where(lane < period, x, jnp.zeros_like(x))
+    out = head
+    for s in range(period, CHUNK, period):
+        out = out | _roll(head, s)
+    return out
+
+
 def _unpack_all_widths(packed, lane):
-    """packed: [R, WORDS]; lane: [R, CHUNK] iota. Returns w8/w16/w32 unpacks
-    ([R, CHUNK] u32 deltas) and the raw (hi, lo) interpretation."""
-    w8_words = jnp.repeat(packed[:, :CHUNK // 4], 4, axis=1)
-    v8 = (w8_words >> ((lane % 4) * 8)) & np.uint32(0xFF)
-    w16_words = jnp.repeat(packed[:, :CHUNK // 2], 2, axis=1)
-    v16 = (w16_words >> ((lane % 2) * 16)) & np.uint32(0xFFFF)
-    v32 = packed[:, :CHUNK]
-    raw_hi = packed[:, :CHUNK]
+    """packed: [R, WORDS]; lane: [R, CHUNK] int32 iota. Returns w8/w16/w32
+    unpacks ([R, CHUNK] u32 deltas) and the raw (hi, lo) interpretation.
+
+    The narrow widths are packed lane-strided (encode_chunks): delta i sits
+    in word i % (CHUNK * w / 32) at bit offset w * (i // (CHUNK * w / 32)),
+    so unpacking is a lane tile plus a per-lane shift."""
+    words = packed[:, :CHUNK]
+    ulane = lane.astype(U32)
+    v8 = (_tile_lanes(words, lane, CHUNK // 4) >> ((ulane >> 5) << 3)) \
+        & np.uint32(0xFF)
+    v16 = (_tile_lanes(words, lane, CHUNK // 2) >> ((ulane >> 6) << 4)) \
+        & np.uint32(0xFFFF)
+    raw_hi = words
     raw_lo = packed[:, CHUNK:]
-    return v8, v16, v32, raw_hi, raw_lo
+    return v8, v16, words, raw_hi, raw_lo
 
 
-def _cumsum64_u32(d):
-    """Exact 64-bit prefix sum of u32 deltas via 16-bit limb cumsums.
+def _cumsum64_u32(d, lane):
+    """Exact 64-bit prefix sum of u32 deltas via 16-bit limb prefix sums.
 
-    cumsum of 128 values each < 2^16 stays < 2^23 — no u32 overflow — so the
-    two limb cumsums are exact; recomposition handles the carry."""
-    lo16 = jnp.cumsum(d & np.uint32(0xFFFF), axis=1, dtype=U32)
-    hi16 = jnp.cumsum(d >> 16, axis=1, dtype=U32)
+    A prefix sum of 128 values each < 2^16 stays < 2^23 — no u32 overflow —
+    so the two limb sums are exact; recomposition handles the carry."""
+    lo16 = lane_cumsum(d & np.uint32(0xFFFF), lane)
+    hi16 = lane_cumsum(d >> 16, lane)
     lo = lo16 + (hi16 << 16)
     carry = (lo < lo16).astype(U32)
     hi = (hi16 >> 16) + carry
@@ -63,12 +114,12 @@ def decode_block(packed, width, a_hi, a_lo):
     """The chunk-decode math, shared by every consumer (this module's Pallas
     kernel, range_search's per-chunk decode, and the XLA "interpret"
     backend in core/packed_store): packed u32 [R, WORDS], width/a_hi/a_lo
-    u32 [R, 1] -> (hi, lo) u32 [R, CHUNK]. Pure jnp — valid both inside and
-    outside kernel bodies."""
-    lane = jax.lax.broadcasted_iota(U32, (packed.shape[0], CHUNK), 1)
+    u32 [R, 1] -> (hi, lo) u32 [R, CHUNK]. Pure jnp plus lane rotations —
+    valid both inside and outside kernel bodies."""
+    lane = jax.lax.broadcasted_iota(I32, (packed.shape[0], CHUNK), 1)
     v8, v16, v32, raw_hi, raw_lo = _unpack_all_widths(packed, lane)
     d = jnp.where(width == 8, v8, jnp.where(width == 16, v16, v32))
-    c_hi, c_lo = _cumsum64_u32(d)
+    c_hi, c_lo = _cumsum64_u32(d, lane)
     hi, lo = _add64(jnp.broadcast_to(a_hi, c_hi.shape),
                     jnp.broadcast_to(a_lo, c_lo.shape), c_hi, c_lo)
     is_raw = width == 64
@@ -90,16 +141,20 @@ def decode_chunks(packed, widths, anchors_hi, anchors_lo,
     -> (code_hi, code_lo) u32 [C, CHUNK]."""
     c = packed.shape[0]
     grid = (c // ROWS,)
+
+    def rows(i):
+        return i, np.int32(0)   # int32 block index even under x64
+
     return pl.pallas_call(
         _decode_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((ROWS, WORDS), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
+            pl.BlockSpec((ROWS, WORDS), rows),
+            pl.BlockSpec((ROWS, 1), rows),
+            pl.BlockSpec((ROWS, 1), rows),
+            pl.BlockSpec((ROWS, 1), rows),
         ],
-        out_specs=[pl.BlockSpec((ROWS, CHUNK), lambda i: (i, 0))] * 2,
+        out_specs=[pl.BlockSpec((ROWS, CHUNK), rows)] * 2,
         out_shape=[jax.ShapeDtypeStruct((c, CHUNK), U32)] * 2,
         interpret=interpret,
     )(packed, widths.reshape(-1, 1), anchors_hi.reshape(-1, 1),
@@ -132,11 +187,14 @@ def encode_chunks(code_hi, code_lo):
                       jnp.where(dmax < 256, 8,
                                 jnp.where(dmax < 65536, 16, 32))).astype(U32)
 
-    # pack each width class (vectorized over all chunks; select at the end)
-    shifts4 = (np.arange(4, dtype=np.uint32) * 8)
-    p8 = (d_lo.reshape(c, CHUNK // 4, 4) << shifts4).sum(-1, dtype=U32)
-    shifts2 = (np.arange(2, dtype=np.uint32) * 16)
-    p16 = (d_lo.reshape(c, CHUNK // 2, 2) << shifts2).sum(-1, dtype=U32)
+    # pack each width class lane-strided (vectorized over all chunks; select
+    # at the end): word j holds deltas j, j + 32, j + 64, j + 96 (w = 8) or
+    # j, j + 64 (w = 16), low bits first — the layout _unpack_all_widths
+    # reads back with lane rotations only
+    shifts4 = (np.arange(4, dtype=np.uint32) * 8)[None, :, None]
+    p8 = (d_lo.reshape(c, 4, CHUNK // 4) << shifts4).sum(1, dtype=U32)
+    shifts2 = (np.arange(2, dtype=np.uint32) * 16)[None, :, None]
+    p16 = (d_lo.reshape(c, 2, CHUNK // 2) << shifts2).sum(1, dtype=U32)
 
     def pad(x):
         return jnp.concatenate(

@@ -24,8 +24,9 @@ lanes and falls back to the rejection sampler for them only.
 Backends (the registry pattern of FINDNEXT / SGNS):
 
   "pallas"           — the Pallas TPU kernel: 8-row f32/u32 tiles, the
-                       [D, D] equality intersection per row on the VPU.
-                       Requires B % 8 == 0 and D % 128 == 0.
+                       [D, D] equality intersection per row on the VPU as
+                       D lane rotations of the prev-window. The auto path
+                       pads off-tile shapes (`factorized_padded`).
   "interpret"        — the SAME selection math (`_choose_math`, shared with
                        the kernel body) over the whole batch in XLA, with
                        membership via per-row binary search on the sorted
@@ -48,7 +49,8 @@ count * weight in f32 in a fixed order, so every comparison resolves
 identically (tested).
 
 "auto" resolves to "pallas" on TPU and "interpret" elsewhere; an explicit
-"pallas" request off-TPU also falls back to "interpret".
+"pallas" request off-TPU runs "interpret" (the same math in XLA). On TPU
+nothing falls back: every shape runs the kernel.
 """
 from __future__ import annotations
 
@@ -59,6 +61,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.delta import lane_cumsum
 
 U32 = jnp.uint32
 I32 = jnp.int32
@@ -103,8 +108,8 @@ def default_backend_request() -> Optional[str]:
 
     Callers that dispatch later (e.g. sample_next passing a static backend
     into a jitted step) must forward THIS value so `factorized_next` can
-    still distinguish an auto pick (shape-aware kernel->interpret fallback)
-    from an explicit kernel request (raises off-tile)."""
+    still distinguish an auto pick (pads off-tile shapes) from an explicit
+    kernel request (raises off-tile)."""
     return _default_backend
 
 
@@ -152,15 +157,43 @@ def member_sorted(nbrs_v, nbrs_p):
     return jax.vmap(row)(nbrs_p, nbrs_v)
 
 
-def _choose_math(nbrs_v, valid, member, prev, u_group, u_rank,
+def member_rolled(nbrs_v, nbrs_p):
+    """The kernel-body intersection: window-v entries compared against every
+    rotation of window-p along the lanes, OR-accumulated — all D x D pairs
+    without the [R, D, D] broadcast Mosaic cannot lay out. Exact-boolean
+    equivalent of `member_allpairs`; the full cycle of rotations makes the
+    result independent of the rotation direction."""
+    d = nbrs_p.shape[1]
+
+    def body(s, acc):
+        return acc | (nbrs_v == pltpu.roll(nbrs_p, s, 1)).astype(I32)
+
+    acc = jax.lax.fori_loop(np.int32(0), np.int32(d), body,
+                            jnp.zeros(nbrs_v.shape, I32))
+    return acc > 0
+
+
+def _umax_rows(x):
+    """Row max of a u32 [R, D] array as [R, 1]: a signed max of the
+    sign-flipped bits (Mosaic reduces only signed integers). Equal to
+    jnp.max(x, axis=1, keepdims=True) bit for bit."""
+    flip = np.int32(-(1 << 31))
+    m = jnp.max(jax.lax.bitcast_convert_type(x, I32) ^ flip, axis=1,
+                keepdims=True)
+    return jax.lax.bitcast_convert_type(m ^ flip, U32)
+
+
+def _choose_rows(nbrs_v, valid, member, prev, u_group, u_rank,
                  inv_p, inv_q):
     """Group-then-member selection, shared verbatim by the Pallas kernel
     body (per 8-row tile) and the "interpret" backend (whole batch).
 
     nbrs_v u32 [R, D]; valid/member bool [R, D]; prev u32 [R, 1];
-    u_group/u_rank f32 [R, 1] in [0, 1). Returns (nxt u32 [R], found
-    bool [R]). Row-independent math, so tile-by-8 and whole-batch execution
-    produce bit-identical results.
+    u_group/u_rank f32 [R, 1] in [0, 1). Returns (nxt u32 [R, 1], found
+    bool [R, 1]). Row-independent math, so tile-by-8 and whole-batch
+    execution produce bit-identical results. Every integer is int32 and the
+    rank prefix sum is a lane scan (kernels/delta.lane_cumsum), so the same
+    code lowers in Mosaic with x64 on.
 
     Group masses are count * weight with the cumulative thresholds formed in
     a fixed order — every backend resolves the group pick identically. The
@@ -169,43 +202,54 @@ def _choose_math(nbrs_v, valid, member, prev, u_group, u_rank,
     group, which is also the measure-correct choice at the top boundary."""
     inv_p = jnp.asarray(inv_p, F32)
     inv_q = jnp.asarray(inv_q, F32)
+    one, two, zero = np.int32(1), np.int32(2), np.int32(0)
     is_prev = valid & (nbrs_v == prev)
     is_common = valid & member & ~is_prev
     is_far = valid & ~member & ~is_prev
-    c0 = jnp.sum(is_prev.astype(I32), axis=1, keepdims=True)    # [R, 1]
-    c1 = jnp.sum(is_common.astype(I32), axis=1, keepdims=True)
-    c2 = jnp.sum(is_far.astype(I32), axis=1, keepdims=True)
 
+    def count(m):
+        return jnp.sum(m.astype(I32), axis=1, keepdims=True, dtype=I32)
+
+    c0, c1, c2 = count(is_prev), count(is_common), count(is_far)  # [R, 1]
     m0 = c0.astype(F32) * inv_p
     m1 = c1.astype(F32)
     m2 = c2.astype(F32) * inv_q
     t = u_group * (m0 + m1 + m2)
     grp = (t >= m0).astype(I32) + (t >= m0 + m1).astype(I32)    # [R, 1]
-    last_nonempty = jnp.where(c2 > 0, 2, jnp.where(c1 > 0, 1, 0))
+    last_nonempty = jnp.where(c2 > 0, two, jnp.where(c1 > 0, one, zero))
     grp = jnp.minimum(grp, last_nonempty)
 
     cg = jnp.where(grp == 0, c0, jnp.where(grp == 1, c1, c2))
-    r = jnp.minimum((u_rank * cg.astype(F32)).astype(I32), cg - 1)
+    r = jnp.minimum((u_rank * cg.astype(F32)).astype(I32), cg - one)
     cls = jnp.where(grp == 0, is_prev.astype(I32),
                     jnp.where(grp == 1, is_common.astype(I32),
                               is_far.astype(I32)))               # [R, D]
-    rank = jnp.cumsum(cls, axis=1)                # 1-indexed at members
-    hit = (cls > 0) & (rank == r + 1)
-    nxt = jnp.max(jnp.where(hit, nbrs_v, jnp.zeros_like(nbrs_v)), axis=1)
-    found = (c0 + c1 + c2)[:, 0] > 0
+    lane = jax.lax.broadcasted_iota(I32, cls.shape, 1)
+    rank = lane_cumsum(cls, lane)                # 1-indexed at members
+    hit = (cls > 0) & (rank == r + one)
+    nxt = _umax_rows(jnp.where(hit, nbrs_v, jnp.zeros_like(nbrs_v)))
+    found = (c0 + c1 + c2) > 0
     return nxt, found
+
+
+def _choose_math(nbrs_v, valid, member, prev, u_group, u_rank,
+                 inv_p, inv_q):
+    """`_choose_rows` with [R]-shaped outputs (nxt u32, found bool): the
+    whole-batch form the XLA backends and the megakernel use."""
+    nxt, found = _choose_rows(nbrs_v, valid, member, prev, u_group, u_rank,
+                              inv_p, inv_q)
+    return nxt[:, 0], found[:, 0]
 
 
 def _intersect_kernel(nv_ref, np_ref, prev_ref, ug_ref, ur_ref,
                       nxt_ref, found_ref, *, inv_p, inv_q):
     nbrs_v = nv_ref[...]
-    nbrs_p = np_ref[...]
     valid = nbrs_v != SENT
-    member = member_allpairs(nbrs_v, nbrs_p)
-    nxt, found = _choose_math(nbrs_v, valid, member, prev_ref[...],
+    member = member_rolled(nbrs_v, np_ref[...])
+    nxt, found = _choose_rows(nbrs_v, valid, member, prev_ref[...],
                               ug_ref[...], ur_ref[...], inv_p, inv_q)
-    nxt_ref[...] = nxt[:, None]
-    found_ref[...] = found[:, None].astype(U32)
+    nxt_ref[...] = nxt
+    found_ref[...] = found.astype(U32)
 
 
 # ----------------------------------------------------------------- backends
@@ -221,8 +265,9 @@ def factorized_next_pallas(nbrs_v, nbrs_p, prev, u_group, u_rank,
     Returns (nxt u32 [B], found bool [B])."""
     b, d = nbrs_v.shape
     grid = (b // ROWS,)
-    win = pl.BlockSpec((ROWS, d), lambda i: (i, 0))
-    scal = pl.BlockSpec((ROWS, 1), lambda i: (i, 0))
+    # int32 block indices even under x64 (Mosaic rejects i64 index maps)
+    win = pl.BlockSpec((ROWS, d), lambda i: (i, np.int32(0)))
+    scal = pl.BlockSpec((ROWS, 1), lambda i: (i, np.int32(0)))
     kernel = functools.partial(_intersect_kernel, inv_p=inv_p, inv_q=inv_q)
     nxt, found = pl.pallas_call(
         kernel,
@@ -275,11 +320,31 @@ def _factorized_ref(nbrs_v, nbrs_p, prev, u_group, u_rank, inv_p, inv_q):
                     cg - 1)
     cls = jnp.where((grp == 0)[:, None], is_prev,
                     jnp.where((grp == 1)[:, None], is_common, is_far))
-    rank = jnp.cumsum(cls.astype(I32), axis=1)
+    rank = jnp.cumsum(cls, axis=1, dtype=I32)
     idx = jnp.argmax((rank == (r + 1)[:, None]) & cls, axis=1)
     nxt = jnp.take_along_axis(nbrs_v, idx[:, None], axis=1)[:, 0]
     found = (c0 + c1 + c2) > 0
     return jnp.where(found, nxt, jnp.zeros_like(nxt)), found
+
+
+def factorized_padded(nbrs_v, nbrs_p, prev, u_group, u_rank, inv_p: float,
+                      inv_q: float, interpret: bool = False):
+    """The Pallas path on any shape: rows padded to the 8-row tile with
+    all-sentinel windows (found=False there), lanes padded to 128 with the
+    sentinel (never matches a vertex). Returns (nxt u32 [B], found [B])."""
+    b, d = nbrs_v.shape
+    padb = (-b) % ROWS
+    padd = (-d) % LANES
+    if padb or padd:
+        nbrs_v = jnp.pad(nbrs_v, ((0, padb), (0, padd)), constant_values=SENT)
+        nbrs_p = jnp.pad(nbrs_p, ((0, padb), (0, padd)), constant_values=SENT)
+        prev = jnp.pad(prev, (0, padb))
+        u_group = jnp.pad(u_group, (0, padb))
+        u_rank = jnp.pad(u_rank, (0, padb))
+    nxt, found = factorized_next_pallas(nbrs_v, nbrs_p, prev, u_group,
+                                        u_rank, inv_p, inv_q,
+                                        interpret=interpret)
+    return nxt[:b], found[:b]
 
 
 def factorized_next(nbrs_v, nbrs_p, prev, u_group, u_rank, p: float,
@@ -292,27 +357,27 @@ def factorized_next(nbrs_v, nbrs_p, prev, u_group, u_rank, p: float,
     the caller to keep the walker in place.
 
     Traceable inside jit/scan for a concrete `backend`. Tiling contract
-    (B % 8 == 0, D % 128 == 0): the auto-resolved kernel path falls back to
-    "interpret" (same math, untiled) on violating shapes; an EXPLICIT
-    "pallas"/"pallas-interpret" request raises, so a kernel-validation run
-    can never silently validate the fallback."""
+    (B % 8 == 0, D % 128 == 0): the auto-resolved kernel path pads off-tile
+    shapes to the tile (`factorized_padded`), so on TPU the kernel runs on
+    every shape; an EXPLICIT "pallas"/"pallas-interpret" request raises on
+    them, so a kernel-validation run sees exactly the shapes it asked for."""
     explicit = backend not in (None, "auto")
     backend = resolve_backend(backend)
     inv_p = float(1.0 / p)
     inv_q = float(1.0 / q)
     if backend in ("pallas", "pallas-interpret"):
         b, d = nbrs_v.shape
+        interpret = backend == "pallas-interpret"
         if b % ROWS or d % LANES:
             if explicit:
                 raise ValueError(
                     f"intersect backend {backend!r} requires B % {ROWS} == 0 "
                     f"and D % {LANES} == 0, got B={b}, D={d}; use "
-                    f"backend='auto' for shape-aware fallback")
-            backend = "interpret"
-        else:
-            return factorized_next_pallas(
-                nbrs_v, nbrs_p, prev, u_group, u_rank, inv_p, inv_q,
-                interpret=(backend == "pallas-interpret"))
+                    f"backend='auto' to pad to the tile")
+            return factorized_padded(nbrs_v, nbrs_p, prev, u_group, u_rank,
+                                     inv_p, inv_q, interpret=interpret)
+        return factorized_next_pallas(nbrs_v, nbrs_p, prev, u_group, u_rank,
+                                      inv_p, inv_q, interpret=interpret)
     if backend == "interpret":
         return _factorized_interpret(nbrs_v, nbrs_p, prev, u_group, u_rank,
                                      inv_p, inv_q)

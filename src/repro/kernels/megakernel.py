@@ -61,7 +61,8 @@ Backends (the registry pattern of FINDNEXT / intersect / SGNS):
   "pallas"           — the fused TPU kernel, grid (B, K): per (lane, k) one
                        candidate chunk is DMA'd/decoded; first-hit-wins
                        accumulation across k; intersection + sampling +
-                       write-back at the last k.
+                       write-back at the last k. It does not lower in Mosaic
+                       yet, so on TPU this request raises.
   "interpret"        — the SAME kernel math (decode_rows, unpair_math, the
                        shared hit/finalize helpers, member_sorted +
                        _choose_math) vectorized over the whole batch in XLA:
@@ -146,7 +147,9 @@ def resolve_backend(name: Optional[str]) -> Optional[str]:
     "auto" consults the registry (whose default is OFF). An explicit
     "pallas" off-TPU runs the same kernel math as "interpret" (the
     established fallback rule), keeping CPU runs free of unlowerable
-    Mosaic calls."""
+    Mosaic calls. On TPU "pallas" raises: the fused kernel's (1, WORDS) /
+    (1, 1) blocks do not lower in Mosaic, and a request for the kernel must
+    never quietly run something else."""
     if name in (None, "off"):
         return None
     if name == "auto":
@@ -156,8 +159,13 @@ def resolve_backend(name: Optional[str]) -> Optional[str]:
     if name not in BACKENDS:
         raise ValueError(f"unknown megakernel backend {name!r}; expected "
                          f"one of {BACKENDS + ('off', 'auto')}")
-    if name == "pallas" and jax.default_backend() != "tpu":
-        return "interpret"
+    if name == "pallas":
+        if jax.default_backend() != "tpu":
+            return "interpret"
+        raise NotImplementedError(
+            "megakernel backend 'pallas' does not compile for TPU (Mosaic "
+            "rejects its (1, WORDS) and (1, 1) blocks); use megakernel='off' "
+            "(the default, unfused Pallas kernels) or 'interpret'")
     return name
 
 

@@ -81,38 +81,14 @@ candidate_chunks = _rs.candidate_chunks
 
 def intersect_next(nbrs_v, nbrs_p, prev, u_group, u_rank, p: float,
                    q: float, interpret: bool | None = None):
-    """Exact factorized node2vec selection via the intersect kernel, with
-    shape-flexible padding: rows padded to the 8-row tile with all-sentinel
-    windows (found=False there), lanes padded to 128 with the sentinel
-    (never matches a vertex). Returns (nxt u32 [B], found bool [B])."""
+    """Exact factorized node2vec selection via the intersect kernel on any
+    shape (intersect.factorized_padded). Returns (nxt u32 [B], found [B])."""
     interpret = _interpret_default() if interpret is None else interpret
-    b, d = nbrs_v.shape
-    padb = (-b) % _intersect.ROWS
-    padd = (-d) % _intersect.LANES
-    if padb or padd:
-        sent = _intersect.SENT
-        nbrs_v = jnp.pad(nbrs_v, ((0, padb), (0, padd)),
-                         constant_values=sent)
-        nbrs_p = jnp.pad(nbrs_p, ((0, padb), (0, padd)),
-                         constant_values=sent)
-        prev = jnp.pad(prev, (0, padb))
-        u_group = jnp.pad(u_group, (0, padb))
-        u_rank = jnp.pad(u_rank, (0, padb))
-    nxt, found = _intersect.factorized_next_pallas(
-        nbrs_v, nbrs_p, prev, u_group, u_rank,
-        float(1.0 / p), float(1.0 / q), interpret=interpret)
-    return nxt[:b], found[:b]
+    return _intersect.factorized_padded(nbrs_v, nbrs_p, prev, u_group,
+                                        u_rank, float(1.0 / p),
+                                        float(1.0 / q), interpret=interpret)
 
 
 def sgns_step(u, v_pos, v_neg, interpret: bool | None = None):
     interpret = _interpret_default() if interpret is None else interpret
-    b, d = u.shape
-    padb = (-b) % _sgns.ROWS
-    padd = (-d) % LANES
-    if padb or padd:
-        u = jnp.pad(u, ((0, padb), (0, padd)))
-        v_pos = jnp.pad(v_pos, ((0, padb), (0, padd)))
-        v_neg = jnp.pad(v_neg, ((0, padb), (0, 0), (0, padd)))
-    loss, du, dvp, dvn = _sgns.sgns_fused(u, v_pos, v_neg,
-                                          interpret=interpret)
-    return (loss[:b], du[:b, :d], dvp[:b, :d], dvn[:b, :, :d])
+    return _sgns.sgns_padded(u, v_pos, v_neg, interpret=interpret)

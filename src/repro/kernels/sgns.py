@@ -19,8 +19,9 @@ Backends (the same registry pattern as FINDNEXT, core/packed_store.py):
 
   "pallas"           — the Pallas TPU kernel: rows tiled by 8 (f32 sublane),
                        D padded to 128 lanes; the [B, K] negative logits are
-                       a batched [8, D] x [D, K] MXU matmul per tile.
-                       Requires B % 8 == 0 and D % 128 == 0.
+                       VPU multiply + lane reductions per tile (Mosaic has
+                       no batched matmul for this contraction). The auto
+                       path pads off-tile shapes (`sgns_padded`).
   "interpret"        — the SAME closed-form kernel math (`_sgns_math`, shared
                        with the kernel body) vectorized over the whole batch
                        in XLA; the automatic CPU fallback, shape-flexible.
@@ -31,8 +32,8 @@ Backends (the same registry pattern as FINDNEXT, core/packed_store.py):
                        backward is checked against (tests/test_sgns.py).
 
 "auto" resolves to "pallas" on TPU and "interpret" elsewhere; an explicit
-"pallas" request off-TPU also falls back to "interpret" so CPU runs never
-hit an unlowerable Mosaic call.
+"pallas" request off-TPU runs "interpret" (the same math in XLA). On TPU
+nothing falls back: every shape runs the kernel.
 """
 from __future__ import annotations
 
@@ -41,6 +42,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 F32 = jnp.float32
@@ -98,13 +100,13 @@ def _sgns_math(u, vp, vn):
     Row-independent math, so tile-by-8 and whole-batch execution produce
     bit-identical results."""
     pos = jnp.sum(u * vp, axis=-1)                        # [R]
-    neg = jnp.einsum("rd,rkd->rk", u, vn,
-                     preferred_element_type=F32)          # [R, K] (MXU)
+    # the per-row dots as VPU multiply + lane reduction: Mosaic cannot
+    # lower the batched [R, D] x [R, K, D] contraction as a matmul
+    neg = jnp.sum(u[:, None, :] * vn, axis=-1)            # [R, K]
     loss = jnp.logaddexp(0.0, -pos) + jnp.logaddexp(0.0, neg).sum(-1)
     gpos = -jax.nn.sigmoid(-pos)                          # dL/dpos
     gneg = jax.nn.sigmoid(neg)                            # dL/dneg  [R, K]
-    du = gpos[:, None] * vp + jnp.einsum(
-        "rk,rkd->rd", gneg, vn, preferred_element_type=F32)
+    du = gpos[:, None] * vp + jnp.sum(gneg[..., None] * vn, axis=1)
     dvp = gpos[:, None] * u
     dvn = gneg[..., None] * u[:, None, :]
     return loss, du, dvp, dvn
@@ -121,7 +123,7 @@ def _sgns_kernel(u_ref, vp_ref, vn_ref, loss_ref, du_ref, dvp_ref, dvn_ref):
 def sgns_reference_loss(u, vp, vn):
     """Per-row reference loss [B] (pure jnp; the "xla-ref" forward)."""
     pos = jnp.sum(u * vp, axis=-1)
-    neg = jnp.einsum("bd,bkd->bk", u, vn)
+    neg = jnp.sum(u[:, None, :] * vn, axis=-1)
     return -(jax.nn.log_sigmoid(pos) + jax.nn.log_sigmoid(-neg).sum(-1))
 
 
@@ -135,9 +137,10 @@ def sgns_fused(u, v_pos, v_neg, interpret: bool = False):
     b, d = u.shape
     k = v_neg.shape[1]
     grid = (b // ROWS,)
-    row2 = pl.BlockSpec((ROWS, d), lambda i: (i, 0))
-    row3 = pl.BlockSpec((ROWS, k, d), lambda i: (i, 0, 0))
-    scal = pl.BlockSpec((ROWS, 1), lambda i: (i, 0))
+    z = np.int32(0)   # int32 block indices even under x64
+    row2 = pl.BlockSpec((ROWS, d), lambda i: (i, z))
+    row3 = pl.BlockSpec((ROWS, k, d), lambda i: (i, z, z))
+    scal = pl.BlockSpec((ROWS, 1), lambda i: (i, z))
     loss, du, dvp, dvn = pl.pallas_call(
         _sgns_kernel,
         grid=grid,
@@ -160,29 +163,44 @@ def _sgns_xla_ref(u, vp, vn):
     return loss, du, dvp, dvn
 
 
+def sgns_padded(u, v_pos, v_neg, interpret: bool = False):
+    """The Pallas path on any shape: rows padded to the 8-row tile and the
+    embedding dim to 128 lanes with zeros (padded lanes add nothing to the
+    dots; padded rows are sliced away). Returns (loss [B], du, dvp, dvn)."""
+    b, d = u.shape
+    padb = (-b) % ROWS
+    padd = (-d) % 128
+    if padb or padd:
+        u = jnp.pad(u, ((0, padb), (0, padd)))
+        v_pos = jnp.pad(v_pos, ((0, padb), (0, padd)))
+        v_neg = jnp.pad(v_neg, ((0, padb), (0, 0), (0, padd)))
+    loss, du, dvp, dvn = sgns_fused(u, v_pos, v_neg, interpret=interpret)
+    return loss[:b], du[:b, :d], dvp[:b, :d], dvn[:b, :, :d]
+
+
 def sgns_apply(u, v_pos, v_neg, backend: Optional[str] = None):
     """Dispatch one fused SGNS forward+backward to the resolved backend.
 
     Traceable (usable inside jit/scan) as long as `backend` is concrete at
     trace time. Returns (loss [B], du, dvp, dvn). Tiling contract
-    (B % 8 == 0, D % 128 == 0): the auto-resolved kernel path falls back to
-    "interpret" (same math, untiled) on violating shapes instead of failing
-    Mosaic lowering; an EXPLICIT "pallas"/"pallas-interpret" request raises,
-    so a kernel-validation run can never silently validate the fallback."""
+    (B % 8 == 0, D % 128 == 0): the auto-resolved kernel path pads
+    off-tile shapes to the tile (`sgns_padded`), so on TPU the kernel runs
+    on every shape; an EXPLICIT "pallas"/"pallas-interpret" request raises
+    on them, so a kernel-validation run sees exactly the shapes it asked
+    for."""
     explicit = backend not in (None, "auto")
     backend = resolve_backend(backend)
     if backend in ("pallas", "pallas-interpret"):
         b, d = u.shape
+        interpret = backend == "pallas-interpret"
         if b % ROWS or d % 128:
             if explicit:
                 raise ValueError(
                     f"sgns backend {backend!r} requires B % {ROWS} == 0 and "
                     f"D % 128 == 0, got B={b}, D={d}; use backend='auto' "
-                    f"for shape-aware fallback")
-            backend = "interpret"
-        else:
-            return sgns_fused(u, v_pos, v_neg,
-                              interpret=(backend == "pallas-interpret"))
+                    f"to pad to the tile")
+            return sgns_padded(u, v_pos, v_neg, interpret=interpret)
+        return sgns_fused(u, v_pos, v_neg, interpret=interpret)
     if backend == "interpret":
         return _sgns_math(u, v_pos, v_neg)
     if backend == "xla-ref":

@@ -66,26 +66,33 @@ def _lt64(a_hi, a_lo, b_hi, b_lo):
     return (a_hi < b_hi) | ((a_hi == b_hi) & (a_lo < b_lo))
 
 
-def _isqrt64(z_hi, z_lo):
+def _isqrt64(z_hi, z_lo, unroll: bool = True):
     """Exact floor(sqrt(z)) for z = (hi, lo), via bit-restoration.
 
     Builds the root from bit 31 down; per bit: candidate = r | (1 << k),
-    keep if candidate^2 <= z. 32 emulated mul+cmp rounds, branch-free.
-    """
-    r = jnp.zeros_like(z_lo)
-    for k in range(31, -1, -1):
-        cand = r | np.uint32(1 << k)
+    keep if candidate^2 <= z. 32 emulated mul+cmp rounds, branch-free —
+    unrolled in kernel bodies, a fori_loop in XLA programs (the unrolled
+    chain of ~700 elementwise ops per call site costs the TPU compiler
+    minutes and can overflow its stack)."""
+    def round_(k, r):
+        cand = r | (np.uint32(1) << (np.uint32(31) - k.astype(U32)))
         c_hi, c_lo = _mul32_64(cand, cand)
-        keep = _le64(c_hi, c_lo, z_hi, z_lo)
-        r = jnp.where(keep, cand, r)
+        return jnp.where(_le64(c_hi, c_lo, z_hi, z_lo), cand, r)
+
+    r = jnp.zeros_like(z_lo)
+    if not unroll:
+        return jax.lax.fori_loop(np.int32(0), np.int32(32), round_, r)
+    for k in range(32):
+        r = round_(np.int32(k), r)
     return r
 
 
 def szudzik_pair_math(x, y):
     """(hi, lo) of Szudzik(x, y), pure u32 math (shared by kernel and tests)."""
-    sq_hi, sq_lo = _mul32_64(jnp.maximum(x, y), jnp.maximum(x, y))
     # x < y:  y^2 + x ; x >= y: x^2 + x + y
     lt = x < y
+    m = jnp.where(lt, y, x)   # max(x, y) by select: Mosaic has no u32 max
+    sq_hi, sq_lo = _mul32_64(m, m)
     add1 = jnp.where(lt, x, x)          # +x in both branches
     add2 = jnp.where(lt, jnp.zeros_like(y), y)
     hi, lo = _add64(sq_hi, sq_lo, jnp.zeros_like(add1), add1)
@@ -93,8 +100,8 @@ def szudzik_pair_math(x, y):
     return hi, lo
 
 
-def szudzik_unpair_math(z_hi, z_lo):
-    s = _isqrt64(z_hi, z_lo)
+def szudzik_unpair_math(z_hi, z_lo, unroll: bool = True):
+    s = _isqrt64(z_hi, z_lo, unroll)
     s2_hi, s2_lo = _mul32_64(s, s)
     rem_hi, rem_lo = _sub64(z_hi, z_lo, s2_hi, s2_lo)
     # rem < s  -> (x, y) = (rem, s)   [rem fits u32 in this branch]
@@ -122,7 +129,8 @@ def _tiled_call(kernel, a, b, interpret: bool):
     """a, b: u32 [M, 128] -> two u32 [M, 128] outputs, tiled (8, 128)."""
     m = a.shape[0]
     grid = (m // BLOCK_ROWS,)
-    spec = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))
+    # int32 block index even under x64 (Mosaic rejects i64 index maps)
+    spec = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, np.int32(0)))
     return pl.pallas_call(
         kernel,
         grid=grid,

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run (deliverable e): lower + compile every
 (architecture x input shape x mesh) cell, print memory/cost analysis, parse
 collective bytes from the compiled HLO, and emit the roofline terms.
@@ -11,17 +7,21 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma2-2b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --mesh multi    # 2x16x16 only
 Results accumulate in dryrun_results.json (one record per cell x mesh).
+`--force-devices N` (default 512, 0 = leave XLA_FLAGS alone) sets the forced
+host device count from `main()` only, so importing this module never
+re-shapes the importer's devices.
 """
-import argparse  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
+import argparse
+import json
+import re
+import time
+import traceback
 
-import jax  # noqa: E402
+import jax
 
-from repro.launch.mesh import (  # noqa: E402
+from repro.launch.mesh import (
     HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh, mesh_size)
+from repro.launch.profile_cell import _force_host_devices
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -163,7 +163,12 @@ def main():
     ap.add_argument("--out", default="dryrun_results.json")
     ap.add_argument("--include-wharf", action="store_true",
                     help="also dry-run the wharf-stream config")
+    ap.add_argument("--force-devices", type=int, default=512,
+                    help="forced host platform device count for the dry-run "
+                         "meshes (0 = don't touch XLA_FLAGS)")
     args = ap.parse_args()
+    if args.force_devices:  # before the first device query initializes XLA
+        _force_host_devices(args.force_devices)
 
     from repro.configs import all_cells, get_arch
 

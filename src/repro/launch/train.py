@@ -157,6 +157,10 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
+    from repro.launch.compile_cache import (enable_compile_cache,
+                                            raise_compiler_stack)
+    raise_compiler_stack()
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     on_restore = None

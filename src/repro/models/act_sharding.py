@@ -32,16 +32,8 @@ def _resolve(logical, axis_names) -> Optional[Tuple[str, ...]]:
     return axes if axes else None
 
 
-def _ambient_mesh():
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:  # jax >= 0.5
-        return get_abstract()
-    from jax._src import mesh as _mesh_lib  # jax 0.4.x: context-set mesh
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
 def constrain(x, *logical_spec):
-    mesh = _ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return x
     spec = tuple(_resolve(l, mesh.axis_names) for l in logical_spec)

@@ -1,5 +1,7 @@
 """Dry-run machinery tests: the trip-count-aware HLO walker (the roofline's
 foundation) and the cell registry/plan builders on a tiny mesh."""
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -21,10 +23,7 @@ def test_xla_cost_analysis_counts_loops_once():
 
     s = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     c = _compile(scanned, s, s)
-    ca = c.cost_analysis()
-    if isinstance(ca, list):  # jax 0.4.x returns [dict]; >=0.5 returns dict
-        ca = ca[0]
-    xla_flops = ca["flops"]
+    xla_flops = c.cost_analysis()["flops"]
     assert xla_flops < 2 * 2 * 128 ** 3  # body counted ~once
 
 
@@ -82,3 +81,35 @@ def test_cell_registry_builds_smoke_plans():
                         ("graphsage-reddit", "full_graph_sm")]:
         plan = build_cell(arch, shape, mesh, smoke=True)
         assert plan.fn is not None and len(plan.args) >= 2
+
+
+def test_compile_cache_dir(monkeypatch):
+    """Entry points keep the persistent cache in $JAX_COMPILATION_CACHE_DIR
+    when set (nothing set in code), else at the fixed <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable_compile_cache()
+    assert path == str(compile_cache.CHECKOUT / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", path)]
+
+
+def test_raise_compiler_stack(monkeypatch):
+    """Entry points give the TPU compiler's fibers a larger stack through
+    LIBTPU_INIT_ARGS, keeping the flags already there and never overriding
+    a stack size the caller set."""
+    from repro.launch import compile_cache
+    flag = compile_cache.COMPILER_STACK_FLAG
+    monkeypatch.delenv("LIBTPU_INIT_ARGS", raising=False)
+    assert compile_cache.raise_compiler_stack(1 << 26) == f"{flag}=67108864"
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", "--xla_tpu_foo=1")
+    assert compile_cache.raise_compiler_stack(1 << 26) == (
+        f"--xla_tpu_foo=1 {flag}=67108864")
+    assert compile_cache.raise_compiler_stack(1 << 20) == (
+        f"--xla_tpu_foo=1 {flag}=67108864")
+    assert os.environ["LIBTPU_INIT_ARGS"].count(flag) == 1

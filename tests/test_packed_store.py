@@ -96,6 +96,23 @@ def test_backends_equivalent_mid_update():
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
 
 
+def test_xla_ref_unpair_independent_of_platform(monkeypatch):
+    """The xla-ref FINDNEXT unpairs in plain u64 math on every platform: on
+    TPU it shares no math with the kernels' 32-bit-limb isqrt."""
+    eng = make_engine()
+    v, w, p, _ = queries_from(eng)
+    want = eng.store.find_next(v, w, p, backend="xla-ref")
+
+    def limbs(z):
+        raise AssertionError("xla-ref FINDNEXT took the limb unpair")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(pairing, "unpair_limbs", limbs)
+    got = eng.store.find_next(v, w, p, backend="xla-ref")
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_traverse_backends_equivalent():
     eng = make_engine()
     stream(eng, n_batches=2)

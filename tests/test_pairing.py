@@ -120,3 +120,15 @@ def test_split_join_u64():
     hi, lo = pairing.split_u64(z)
     np.testing.assert_array_equal(np.asarray(pairing.join_u64(hi, lo)),
                                   np.asarray(z))
+
+
+@given(st.lists(st.integers(min_value=0, max_value=2**64 - 1), min_size=1,
+                max_size=64))
+@settings(max_examples=200, deadline=None)
+def test_unpair_limbs_equals_unpair(zs):
+    """The 32-bit-limb unpair (the TPU path) equals the plain u64 one on
+    every uint64, including non-code values."""
+    zs = zs + [0, 1, 2**32 - 1, 2**32, 2**64 - 1, (2**32 - 1) ** 2]
+    z = jnp.asarray(zs, jnp.uint64)
+    for a, b in zip(pairing.unpair_limbs(z), pairing.szudzik_unpair_u64(z)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
