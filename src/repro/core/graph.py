@@ -18,6 +18,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace
+
 U64 = jnp.uint64
 U32 = jnp.uint32
 I32 = jnp.int32
@@ -137,8 +139,9 @@ class StreamingGraph:
     def apply_batch(self, ins_src, ins_dst, del_src, del_dst,
                     undirected: bool = True) -> "StreamingGraph":
         """One graph update delta-G (deletions then insertions, paper §3.1)."""
-        g = self.delete_edges(del_src, del_dst, undirected=undirected)
-        return g.insert_edges(ins_src, ins_dst, undirected=undirected)
+        with trace.scope(trace.GRAPH_UPDATE):
+            g = self.delete_edges(del_src, del_dst, undirected=undirected)
+            return g.insert_edges(ins_src, ins_dst, undirected=undirected)
 
     # -- queries --------------------------------------------------------------
 

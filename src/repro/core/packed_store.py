@@ -42,6 +42,7 @@ import numpy as np
 from repro.core import pairing
 from repro.kernels import ops
 from repro.kernels.delta import CHUNK, decode_block, packed_nbytes
+from repro.obs import trace
 
 U64 = jnp.uint64
 U32 = jnp.uint32
@@ -185,13 +186,15 @@ def packed_search_xla(packed, widths, a_hi, a_lo, chunk_idx, f_targets):
 def packed_search(packed, widths, a_hi, a_lo, chunk_idx, f_targets,
                   backend: str):
     """Dispatch a packed-chunk FINDNEXT to the resolved backend."""
-    if backend == "pallas" or backend == "pallas-interpret":
-        return ops.find_next_packed(packed, widths, a_hi, a_lo,
-                                    chunk_idx, jnp.asarray(f_targets, U32),
-                                    interpret=(backend == "pallas-interpret"))
-    if backend == "interpret":
-        return packed_search_xla(packed, widths, a_hi, a_lo, chunk_idx,
-                                 f_targets)
+    with trace.scope(trace.FINDNEXT):
+        if backend == "pallas" or backend == "pallas-interpret":
+            return ops.find_next_packed(
+                packed, widths, a_hi, a_lo, chunk_idx,
+                jnp.asarray(f_targets, U32),
+                interpret=(backend == "pallas-interpret"))
+        if backend == "interpret":
+            return packed_search_xla(packed, widths, a_hi, a_lo, chunk_idx,
+                                     f_targets)
     raise ValueError(f"packed_search cannot serve backend {backend!r}")
 
 

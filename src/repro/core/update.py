@@ -52,6 +52,7 @@ from repro.core.store import WalkStore, PAD_EPOCH
 from repro.core.utils import compact_nonzero
 from repro.core.walkers import sample_next
 from repro.kernels import megakernel
+from repro.obs import trace
 
 U64 = jnp.uint64
 U32 = jnp.uint32
@@ -263,27 +264,28 @@ class WalkEngine:
         """One graph update delta-G -> walk updates (Algorithm 2), fully
         jitted (fixed shapes via the pending buffer). Returns the affected
         count as a device scalar — no sync on the hot path."""
-        e = lambda: jnp.zeros((0,), U32)
-        ins_src = e() if ins_src is None else jnp.asarray(ins_src, U32)
-        ins_dst = e() if ins_dst is None else jnp.asarray(ins_dst, U32)
-        del_src = e() if del_src is None else jnp.asarray(del_src, U32)
-        del_dst = e() if del_dst is None else jnp.asarray(del_dst, U32)
+        with trace.phase("wharf/update"):
+            e = lambda: jnp.zeros((0,), U32)
+            ins_src = e() if ins_src is None else jnp.asarray(ins_src, U32)
+            ins_dst = e() if ins_dst is None else jnp.asarray(ins_dst, U32)
+            del_src = e() if del_src is None else jnp.asarray(del_src, U32)
+            del_dst = e() if del_dst is None else jnp.asarray(del_dst, U32)
 
-        if self._n_pending_host == self.max_pending:
-            self.merge()
+            if self._n_pending_host == self.max_pending:
+                self.merge()
 
-        s = self.state
-        self.state = _update_jit(
-            s.graph, s.store, s.pending, s.n_pending, s.epoch,
-            s.total_affected, s.overflow,
-            ins_src, ins_dst, del_src, del_dst, key,
-            self.cfg, self.rewalk_capacity, self._mav_capacity())
-        self._n_pending_host += 1
-        self._epoch_host += 1
+            s = self.state
+            self.state = _update_jit(
+                s.graph, s.store, s.pending, s.n_pending, s.epoch,
+                s.total_affected, s.overflow,
+                ins_src, ins_dst, del_src, del_dst, key,
+                self.cfg, self.rewalk_capacity, self._mav_capacity())
+            self._n_pending_host += 1
+            self._epoch_host += 1
 
-        if self.merge_policy == "eager":
-            self.merge()
-        return self.state.last_affected
+            if self.merge_policy == "eager":
+                self.merge()
+            return self.state.last_affected
 
     def run_stream(self, key, ins_src, ins_dst, del_src=None, del_dst=None,
                    return_masks: bool = False):
@@ -312,40 +314,44 @@ class WalkEngine:
         With `cfg.metrics`, `self.metrics` (a StreamMetrics pytree, also
         donated) accumulates the stream's counters on device — the return
         value is unchanged; read `engine.metrics` at stream end.
-        """
-        ins_src = jnp.asarray(ins_src, U32)
-        ins_dst = jnp.asarray(ins_dst, U32)
-        n_batches = ins_src.shape[0]
-        if del_src is None:
-            del_src = jnp.zeros((n_batches, 0), U32)
-            del_dst = jnp.zeros((n_batches, 0), U32)
-        else:
-            del_src = jnp.asarray(del_src, U32)
-            del_dst = jnp.asarray(del_dst, U32)
-        keys = jax.random.split(key, n_batches)
 
-        # outstanding read pins suppress donation (pin contract, §11): the
-        # pinned snapshots keep serving the pre-stream buffers bit-identically
-        pinned = self._pins > 0
-        if self.cfg.metrics:
-            entry = (_run_stream_obs_jit_nodonate if pinned
-                     else _run_stream_obs_jit)
-            self.state, self.metrics, out = entry(
-                self.state, self.metrics, keys, ins_src, ins_dst, del_src,
-                del_dst, cfg=self.cfg, capacity=self.rewalk_capacity,
-                mav_capacity=self._mav_capacity(),
-                max_pending=self.max_pending,
-                merge_policy=self.merge_policy, merge_impl=self.merge_impl,
-                with_masks=return_masks)
-        else:
-            entry = _run_stream_jit_nodonate if pinned else _run_stream_jit
-            self.state, out = entry(
-                self.state, keys, ins_src, ins_dst, del_src, del_dst,
-                cfg=self.cfg, capacity=self.rewalk_capacity,
-                mav_capacity=self._mav_capacity(),
-                max_pending=self.max_pending,
-                merge_policy=self.merge_policy, merge_impl=self.merge_impl,
-                with_masks=return_masks)
+        Host spans (obs/trace.py): `wharf/run_stream` around the call, with
+        `wharf/stage` (arguments to the device, key split) and
+        `wharf/dispatch` (the jitted entry) inside it.
+        """
+        n_batches = len(ins_src)
+        with trace.phase("wharf/run_stream", n_batches=n_batches):
+            with trace.phase("wharf/stage"):
+                ins_src = jnp.asarray(ins_src, U32)
+                ins_dst = jnp.asarray(ins_dst, U32)
+                if del_src is None:
+                    del_src = jnp.zeros((n_batches, 0), U32)
+                    del_dst = jnp.zeros((n_batches, 0), U32)
+                else:
+                    del_src = jnp.asarray(del_src, U32)
+                    del_dst = jnp.asarray(del_dst, U32)
+                keys = jax.random.split(key, n_batches)
+            # outstanding read pins suppress donation (pin contract, §11):
+            # the pinned snapshots keep serving the pre-stream buffers
+            # bit-identically
+            pinned = self._pins > 0
+            kw = dict(cfg=self.cfg, capacity=self.rewalk_capacity,
+                      mav_capacity=self._mav_capacity(),
+                      max_pending=self.max_pending,
+                      merge_policy=self.merge_policy,
+                      merge_impl=self.merge_impl, with_masks=return_masks)
+            with trace.phase("wharf/dispatch"):
+                if self.cfg.metrics:
+                    entry = (_run_stream_obs_jit_nodonate if pinned
+                             else _run_stream_obs_jit)
+                    self.state, self.metrics, out = entry(
+                        self.state, self.metrics, keys, ins_src, ins_dst,
+                        del_src, del_dst, **kw)
+                else:
+                    entry = (_run_stream_jit_nodonate if pinned
+                             else _run_stream_jit)
+                    self.state, out = entry(self.state, keys, ins_src,
+                                            ins_dst, del_src, del_dst, **kw)
 
         # host mirrors: the merge schedule is data-independent
         self._n_pending_host = pending_after_stream(
@@ -365,7 +371,9 @@ class WalkEngine:
         Both produce identical stores (tested)."""
         if not self._n_pending_host:
             return
-        self.state = _merge_state_jit(self.state, self.cfg, self.merge_impl)
+        with trace.phase("wharf/consolidate"):
+            self.state = _merge_state_jit(self.state, self.cfg,
+                                          self.merge_impl)
         self._n_pending_host = 0
 
     def walk_matrix(self):
@@ -406,60 +414,63 @@ def _apply_update(state: EngineState, ins_src, ins_dst, del_src, del_dst,
     # 1. apply the graph update (paper: MAV is built while updating)
     graph = state.graph.apply_batch(ins_src, ins_dst, del_src, del_dst)
     store, pending = state.store, state.pending
-    new_epoch = state.epoch + jnp.asarray(1, U32)
+    with trace.scope(trace.WRITE_BACK):
+        new_epoch = state.epoch + jnp.asarray(1, U32)
 
     # 2. MAV — output-sensitive (paper §6.1): only the touched vertices'
     # walk-tree SEGMENTS of the base store are gathered and decoded (the
     # shared core/mav.py segment gather); pending entries carry slots
     # explicitly, so they join the reduction without a u64 unpair.
-    touched_v = jnp.zeros((store.n_vertices,), bool)
-    for arr in (ins_src, ins_dst, del_src, del_dst):
-        if arr.shape[0] > 0:
-            touched_v = touched_v.at[arr.astype(I32)].set(True)
+    with trace.scope(trace.MAV):
+        touched_v = jnp.zeros((store.n_vertices,), bool)
+        for arr in (ins_src, ins_dst, del_src, del_dst):
+            if arr.shape[0] > 0:
+                touched_v = touched_v.at[arr.astype(I32)].set(True)
 
-    g_owner, g_code, g_epoch, g_valid, total = gather_touched_segments(
-        store, touched_v, mav_capacity)
-    overflow = total > mav_capacity
-    g_f, _ = pairing.szudzik_unpair(jnp.where(g_valid, g_code,
-                                              jnp.zeros_like(g_code)))
-    g_w = (g_f // jnp.asarray(store.length, U64)).astype(I32)
-    g_p = (g_f % jnp.asarray(store.length, U64)).astype(I32)
-    g_touched = touched_v[g_owner.astype(I32)] & g_valid
+        g_owner, g_code, g_epoch, g_valid, total = gather_touched_segments(
+            store, touched_v, mav_capacity)
+        overflow = total > mav_capacity
+        g_f, _ = pairing.szudzik_unpair(jnp.where(g_valid, g_code,
+                                                  jnp.zeros_like(g_code)))
+        g_w = (g_f // jnp.asarray(store.length, U64)).astype(I32)
+        g_p = (g_f % jnp.asarray(store.length, U64)).astype(I32)
+        g_touched = touched_v[g_owner.astype(I32)] & g_valid
 
-    p_owner = pending.owner.reshape(-1)
-    p_slot = pending.slot.reshape(-1)
-    p_epoch = pending.epoch.reshape(-1)
-    p_valid = p_epoch != PAD_EPOCH
-    p_w = p_slot // store.length
-    p_p = p_slot % store.length
-    p_touched = touched_v[p_owner.astype(I32)] & p_valid
+        p_owner = pending.owner.reshape(-1)
+        p_slot = pending.slot.reshape(-1)
+        p_epoch = pending.epoch.reshape(-1)
+        p_valid = p_epoch != PAD_EPOCH
+        p_w = p_slot // store.length
+        p_p = p_slot % store.length
+        p_touched = touched_v[p_owner.astype(I32)] & p_valid
 
-    mav = _pmin_from_wpo(
-        jnp.concatenate([g_w, p_w]), jnp.concatenate([g_p, p_p]),
-        jnp.concatenate([g_owner, p_owner]),
-        jnp.concatenate([g_epoch, p_epoch]), store.slot_epoch,
-        jnp.concatenate([g_touched, p_touched]),
-        jnp.concatenate([g_valid, p_valid]),
-        store.length, store.n_walks)
+        mav = _pmin_from_wpo(
+            jnp.concatenate([g_w, p_w]), jnp.concatenate([g_p, p_p]),
+            jnp.concatenate([g_owner, p_owner]),
+            jnp.concatenate([g_epoch, p_epoch]), store.slot_epoch,
+            jnp.concatenate([g_touched, p_touched]),
+            jnp.concatenate([g_valid, p_valid]),
+            store.length, store.n_walks)
 
     # 3-5. re-walk affected walks into a fresh version block
     block, slot_epoch, n_aff, aux = _rewalk(key, graph, store, pending, mav,
                                             new_epoch, cfg, capacity)
-    pending = PendingBlocks(
-        owner=jax.lax.dynamic_update_index_in_dim(
-            pending.owner, block.owner, state.n_pending, 0),
-        code=jax.lax.dynamic_update_index_in_dim(
-            pending.code, block.code, state.n_pending, 0),
-        epoch=jax.lax.dynamic_update_index_in_dim(
-            pending.epoch, block.epoch, state.n_pending, 0),
-        slot=jax.lax.dynamic_update_index_in_dim(
-            pending.slot, block.slot, state.n_pending, 0))
-    n_aff = n_aff.astype(I32)
-    return EngineState(
-        graph=graph, store=store.replace(slot_epoch=slot_epoch),
-        pending=pending, n_pending=state.n_pending + 1, epoch=new_epoch,
-        last_affected=n_aff, total_affected=state.total_affected + n_aff,
-        overflow=state.overflow | overflow), aux
+    with trace.scope(trace.WRITE_BACK):
+        pending = PendingBlocks(
+            owner=jax.lax.dynamic_update_index_in_dim(
+                pending.owner, block.owner, state.n_pending, 0),
+            code=jax.lax.dynamic_update_index_in_dim(
+                pending.code, block.code, state.n_pending, 0),
+            epoch=jax.lax.dynamic_update_index_in_dim(
+                pending.epoch, block.epoch, state.n_pending, 0),
+            slot=jax.lax.dynamic_update_index_in_dim(
+                pending.slot, block.slot, state.n_pending, 0))
+        n_aff = n_aff.astype(I32)
+        return EngineState(
+            graph=graph, store=store.replace(slot_epoch=slot_epoch),
+            pending=pending, n_pending=state.n_pending + 1, epoch=new_epoch,
+            last_affected=n_aff, total_affected=state.total_affected + n_aff,
+            overflow=state.overflow | overflow), aux
 
 
 def _merged_store(store: WalkStore, pending: PendingBlocks,
@@ -477,10 +488,11 @@ def _merged_store(store: WalkStore, pending: PendingBlocks,
 
 def _merge_state(state: EngineState, cfg: WalkConfig,
                  merge_impl: str) -> EngineState:
-    return state.replace(
-        store=_merged_store(state.store, state.pending, merge_impl),
-        pending=PendingBlocks.empty_like(state.pending),
-        n_pending=jnp.asarray(0, I32))
+    with trace.scope(trace.MERGE):
+        return state.replace(
+            store=_merged_store(state.store, state.pending, merge_impl),
+            pending=PendingBlocks.empty_like(state.pending),
+            n_pending=jnp.asarray(0, I32))
 
 
 _merge_state_jit = jax.jit(_merge_state,
@@ -577,9 +589,10 @@ def stream_step_aux(state: EngineState, key, ins_src, ins_dst, del_src,
     the default `metrics=None` path traces the exact same HLO as before
     (tests/test_obs.py)."""
     merge = partial(_merge_state, cfg=cfg, merge_impl=merge_impl)
-    forced = state.n_pending >= jnp.asarray(max_pending, I32)
     overflow_before = state.overflow
-    state = jax.lax.cond(forced, merge, lambda s: s, state)
+    with trace.scope(trace.MERGE):
+        forced = state.n_pending >= jnp.asarray(max_pending, I32)
+        state = jax.lax.cond(forced, merge, lambda s: s, state)
     state, aux = _apply_update(state, ins_src, ins_dst, del_src, del_dst,
                                key, cfg, capacity, mav_capacity)
     if metrics is not None:
@@ -728,12 +741,15 @@ def _rewalk(key, graph: StreamingGraph, store: WalkStore,
     (tests/test_megakernel.py), so every driver inherits the fusion from
     the config alone."""
     length = store.length
-    affected = mav.p_min < length
-    walk_ids, lane_valid = compact_nonzero(affected, size=capacity)
-    walk_ids = walk_ids.astype(U32)
-    p_min = mav.p_min[walk_ids]
-    v_at_pmin = mav.v_min[walk_ids]
-    ps = jnp.arange(length, dtype=I32)
+    # the affected walks, compacted into lanes, are the MAV's output
+    with trace.scope(trace.MAV):
+        affected = mav.p_min < length
+        walk_ids, lane_valid = compact_nonzero(affected, size=capacity)
+        walk_ids = walk_ids.astype(U32)
+        p_min = mav.p_min[walk_ids]
+        v_at_pmin = mav.v_min[walk_ids]
+    with trace.scope(trace.SAMPLE):
+        ps = jnp.arange(length, dtype=I32)
 
     req = (cfg.megakernel if cfg.megakernel != "auto"
            else megakernel.default_backend_request())
@@ -741,26 +757,32 @@ def _rewalk(key, graph: StreamingGraph, store: WalkStore,
 
     if backend is not None:
         megakernel.check_supported(store, cfg, backend)
-        owners, codes, emits = megakernel.fused_scan(
-            key, graph, store, pending, walk_ids, lane_valid, p_min,
-            v_at_pmin, cfg, backend)
+        # one fused dispatch per step (FINDNEXT through write-back),
+        # charged to the sampler; its kernel is wharf_megakernel
+        with trace.scope(trace.SAMPLE):
+            owners, codes, emits = megakernel.fused_scan(
+                key, graph, store, pending, walk_ids, lane_valid, p_min,
+                v_at_pmin, cfg, backend)
     else:
         if cfg.model.order == 2:
-            start = walk_start_vertex(walk_ids, cfg.n_walks_per_vertex)
-            # O(p_min) FINDNEXTs per walk; paper notes the same requirement.
-            # The prefix must reflect base + pending (earlier version blocks
-            # may have rewritten prefix slots), so it reads through the
-            # overlay — this is what lets node2vec streams run without
-            # per-batch merges.
-            view = (store if pending is None
-                    else Overlay.build(store, pending))
-            prefix = view.traverse(walk_ids, start, length - 1)
-            prev0 = prefix[jnp.arange(capacity), jnp.maximum(p_min - 1, 0)]
+            # O(p_min) FINDNEXTs per walk; paper notes the same
+            # requirement. The prefix must reflect base + pending (earlier
+            # version blocks may have rewritten prefix slots), so it reads
+            # through the overlay — this is what lets node2vec streams run
+            # without per-batch merges.
+            with trace.scope(trace.FINDNEXT):
+                start = walk_start_vertex(walk_ids, cfg.n_walks_per_vertex)
+                view = (store if pending is None
+                        else Overlay.build(store, pending))
+                prefix = view.traverse(walk_ids, start, length - 1)
+                prev0 = prefix[jnp.arange(capacity),
+                               jnp.maximum(p_min - 1, 0)]
         else:
             prev0 = v_at_pmin
 
-        w64 = walk_ids.astype(U64)
-        l64 = jnp.asarray(length, U64)
+        with trace.scope(trace.WRITE_BACK):
+            w64 = walk_ids.astype(U64)
+            l64 = jnp.asarray(length, U64)
 
         def step(carry, inp):
             cur, prev = carry
@@ -769,37 +791,43 @@ def _rewalk(key, graph: StreamingGraph, store: WalkStore,
             nxt = sample_next(kp, graph, cur, prev, cfg.model)
             is_term = p == length - 1
             nxt_eff = jnp.where(is_term, cur, nxt)
-            code = pairing.szudzik_pair(w64 * l64 + p.astype(U64),
-                                        nxt_eff.astype(U64))
-            emit = lane_valid & (p >= p_min)
+            with trace.scope(trace.WRITE_BACK):
+                code = pairing.szudzik_pair(w64 * l64 + p.astype(U64),
+                                            nxt_eff.astype(U64))
+                emit = lane_valid & (p >= p_min)
             owner = cur
             prev_new = jnp.where(p >= p_min, cur, prev)
             cur_new = jnp.where((p >= p_min) & ~is_term, nxt, cur)
             return (cur_new, prev_new), (owner, code, emit)
 
-        keys = jax.random.split(key, length)
-        (_, _), (owners, codes, emits) = jax.lax.scan(
-            step, (v_at_pmin, prev0), (ps, keys))
-    owners = owners.T.reshape(-1)        # [capacity * l]
-    codes = codes.T.reshape(-1)
-    emits = emits.T.reshape(-1)
+        with trace.scope(trace.SAMPLE):
+            keys = jax.random.split(key, length)
+            (_, _), (owners, codes, emits) = jax.lax.scan(
+                step, (v_at_pmin, prev0), (ps, keys))
+    with trace.scope(trace.WRITE_BACK):
+        owners = owners.T.reshape(-1)        # [capacity * l]
+        codes = codes.T.reshape(-1)
+        emits = emits.T.reshape(-1)
 
-    epoch = jnp.where(emits, new_epoch, PAD_EPOCH).astype(U32)
-    owners = jnp.where(emits, owners, 0).astype(U32)
-    codes = jnp.where(emits, codes, jnp.asarray(0, U64))
+        epoch = jnp.where(emits, new_epoch, PAD_EPOCH).astype(U32)
+        owners = jnp.where(emits, owners, 0).astype(U32)
+        codes = jnp.where(emits, codes, jnp.asarray(0, U64))
 
-    # 5. bump slot versions for all rewritten slots (w, p >= p_min)
-    slot_w = jnp.repeat(walk_ids.astype(I32), length)
-    slot_p = jnp.tile(ps, capacity)
-    slots = jnp.clip(slot_w * length + slot_p, 0, store.n_walks * length - 1)
-    # max with 0 is a no-op for non-emitting lanes, so no masking needed
-    slot_epoch = store.slot_epoch.at[slots].max(
-        jnp.where(emits, new_epoch, jnp.asarray(0, U32)))
+        # 5. bump slot versions for all rewritten slots (w, p >= p_min)
+        slot_w = jnp.repeat(walk_ids.astype(I32), length)
+        slot_p = jnp.tile(ps, capacity)
+        slots = jnp.clip(slot_w * length + slot_p, 0,
+                         store.n_walks * length - 1)
+        # max with 0 is a no-op for non-emitting lanes, so no masking needed
+        slot_epoch = store.slot_epoch.at[slots].max(
+            jnp.where(emits, new_epoch, jnp.asarray(0, U32)))
 
-    n_aff = jnp.sum(affected)
-    block = VersionBlock(owner=owners, code=codes, epoch=epoch,
-                         slot=jnp.where(emits, slots, 0).astype(I32),
-                         n_new=jnp.sum(emits).astype(I32))
+    with trace.scope(trace.MAV):
+        n_aff = jnp.sum(affected)
+    with trace.scope(trace.WRITE_BACK):
+        block = VersionBlock(owner=owners, code=codes, epoch=epoch,
+                             slot=jnp.where(emits, slots, 0).astype(I32),
+                             n_new=jnp.sum(emits).astype(I32))
     aux = UpdateAux(walk_ids=walk_ids, lane_valid=lane_valid, p_min=p_min)
     return block, slot_epoch, n_aff, aux
 

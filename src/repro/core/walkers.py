@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 from repro.core.utils import compact_nonzero
 from repro.kernels import intersect
+from repro.obs import trace
 
 U32 = jnp.uint32
 I32 = jnp.int32
@@ -203,9 +204,10 @@ def _node2vec_factorized_step(key, graph, v, prev, p, q, n_trials, dmax,
     u = jax.random.uniform(k_u, (b, 2), dtype=jnp.float32)
     nbrs_v, deg_v = _neighbor_window(graph, v, dmax)
     nbrs_p, deg_p = _neighbor_window(graph, prev, dmax)
-    nxt, found = intersect.factorized_next(
-        nbrs_v, nbrs_p, jnp.asarray(prev, U32), u[:, 0], u[:, 1], p, q,
-        backend=backend)
+    with trace.scope(trace.INTERSECT):
+        nxt, found = intersect.factorized_next(
+            nbrs_v, nbrs_p, jnp.asarray(prev, U32), u[:, 0], u[:, 1], p, q,
+            backend=backend)
     nxt = jnp.where(found, nxt, v)  # isolated vertices stay in place
     overflow = (deg_v > dmax) | (deg_p > dmax)
     return rejection_fallback(k_fb, graph, v, prev, overflow, nxt, p, q,

@@ -69,6 +69,7 @@ from repro.core.update import EngineState, PendingBlocks, VersionBlock
 from repro.core.utils import compact_nonzero
 from repro.core.walkers import sample_next_sharded
 from repro.distr.handoff import exchange_frontier, shard_of_vertex
+from repro.obs import trace
 
 U64 = jnp.uint64
 U32 = jnp.uint32
@@ -118,12 +119,13 @@ def _pmin_keys(best):
     two int32 collectives — TPUs all-reduce 64-bit values only as sums: the
     min of the high words (p), then the min of the low words (owner) over
     the shards holding that p. Equal to `lax.pmin(best)` for owner < 2^31."""
-    hi = (best >> 32).astype(I32)
-    lo = (best & jnp.asarray(0xFFFFFFFF, jnp.int64)).astype(I32)
-    hi_min = jax.lax.pmin(hi, AXIS)
-    lo = jnp.where(hi == hi_min, lo, jnp.iinfo(jnp.int32).max)
-    lo_min = jax.lax.pmin(lo, AXIS)
-    return (hi_min.astype(jnp.int64) << 32) | lo_min.astype(jnp.int64)
+    with trace.scope(trace.COLLECTIVE):
+        hi = (best >> 32).astype(I32)
+        lo = (best & jnp.asarray(0xFFFFFFFF, jnp.int64)).astype(I32)
+        hi_min = jax.lax.pmin(hi, AXIS)
+        lo = jnp.where(hi == hi_min, lo, jnp.iinfo(jnp.int32).max)
+        lo_min = jax.lax.pmin(lo, AXIS)
+        return (hi_min.astype(jnp.int64) << 32) | lo_min.astype(jnp.int64)
 
 
 # ------------------------------------------------------- local graph update
@@ -154,22 +156,23 @@ def _local_apply_batch(graph: StreamingGraph, ins_src, ins_dst, del_src,
                        del_dst, spec: ShardSpec, my_shard):
     """Shard-local graph delta: deletions then insertions (both undirected),
     keeping only the directions whose source vertex this shard owns."""
-    codes = graph.codes
-    overflow = jnp.asarray(False)
-    if del_src.shape[0] > 0:
-        gone = jnp.concatenate([edge_code(del_src, del_dst),
-                                edge_code(del_dst, del_src)])
-        codes = _local_delete(codes, gone)
-    if ins_src.shape[0] > 0:
-        new = jnp.concatenate([edge_code(ins_src, ins_dst),
-                               edge_code(ins_dst, ins_src)])
-        owners = jnp.concatenate([ins_src, ins_dst])
-        mine = shard_of_vertex(owners, spec.vps) == my_shard
-        codes, overflow = _local_insert(codes, jnp.where(mine, new, SENTINEL),
-                                        spec.edge_capacity)
-    num = jnp.sum(codes != SENTINEL).astype(I32)
-    return StreamingGraph(codes, graph._rebuild_offsets(codes, num), num,
-                          graph.n_vertices), overflow
+    with trace.scope(trace.GRAPH_UPDATE):
+        codes = graph.codes
+        overflow = jnp.asarray(False)
+        if del_src.shape[0] > 0:
+            gone = jnp.concatenate([edge_code(del_src, del_dst),
+                                    edge_code(del_dst, del_src)])
+            codes = _local_delete(codes, gone)
+        if ins_src.shape[0] > 0:
+            new = jnp.concatenate([edge_code(ins_src, ins_dst),
+                                   edge_code(ins_dst, ins_src)])
+            owners = jnp.concatenate([ins_src, ins_dst])
+            mine = shard_of_vertex(owners, spec.vps) == my_shard
+            codes, overflow = _local_insert(
+                codes, jnp.where(mine, new, SENTINEL), spec.edge_capacity)
+        num = jnp.sum(codes != SENTINEL).astype(I32)
+        return StreamingGraph(codes, graph._rebuild_offsets(codes, num), num,
+                              graph.n_vertices), overflow
 
 
 # -------------------------------------------------------------- local merge
@@ -210,11 +213,13 @@ def _local_consolidated_store(store: WalkStore, pending: PendingBlocks):
 
 
 def _local_merge_state(state: EngineState) -> EngineState:
-    store, overflow = _local_consolidated_store(state.store, state.pending)
-    return state.replace(store=store,
-                         pending=PendingBlocks.empty_like(state.pending),
-                         n_pending=jnp.asarray(0, I32),
-                         overflow=state.overflow | overflow)
+    with trace.scope(trace.MERGE):
+        store, overflow = _local_consolidated_store(state.store,
+                                                    state.pending)
+        return state.replace(store=store,
+                             pending=PendingBlocks.empty_like(state.pending),
+                             n_pending=jnp.asarray(0, I32),
+                             overflow=state.overflow | overflow)
 
 
 # ------------------------------------------------------------ sharded update
@@ -237,16 +242,19 @@ def _sharded_rewalk(key, graph: StreamingGraph, store: WalkStore, mav,
     the scan carry (DESIGN.md §10) and appends an obs dict to the return —
     pure reads of `dest`, so the frontier itself is untouched."""
     length = store.length
-    affected = mav.p_min < length
-    walk_ids, lane_valid = compact_nonzero(affected, size=capacity)
-    walk_ids = walk_ids.astype(U32)
-    p_min = mav.p_min[walk_ids]
-    v_at_pmin = mav.v_min[walk_ids]
-    spawn_here = lane_valid & (shard_of_vertex(v_at_pmin, spec.vps)
-                               == my_shard)
-    ps = jnp.arange(length, dtype=I32)
-    w64 = walk_ids.astype(U64)
-    l64 = jnp.asarray(length, U64)
+    with trace.scope(trace.MAV):
+        affected = mav.p_min < length
+        walk_ids, lane_valid = compact_nonzero(affected, size=capacity)
+        walk_ids = walk_ids.astype(U32)
+        p_min = mav.p_min[walk_ids]
+        v_at_pmin = mav.v_min[walk_ids]
+    with trace.scope(trace.SAMPLE):
+        spawn_here = lane_valid & (shard_of_vertex(v_at_pmin, spec.vps)
+                                   == my_shard)
+        ps = jnp.arange(length, dtype=I32)
+    with trace.scope(trace.WRITE_BACK):
+        w64 = walk_ids.astype(U64)
+        l64 = jnp.asarray(length, U64)
 
     def step(carry, inp):
         if with_obs:
@@ -261,8 +269,9 @@ def _sharded_rewalk(key, graph: StreamingGraph, store: WalkStore, mav,
         nxt = sample_next_sharded(kp, graph, cur, cfg.model)
         is_term = p == length - 1
         nxt_eff = jnp.where(is_term, cur, nxt)
-        code = pairing.szudzik_pair(w64 * l64 + p.astype(U64),
-                                    nxt_eff.astype(U64))
+        with trace.scope(trace.WRITE_BACK):
+            code = pairing.szudzik_pair(w64 * l64 + p.astype(U64),
+                                        nxt_eff.astype(U64))
         emit = mine
         owner = cur
         cont = mine & ~is_term
@@ -276,52 +285,58 @@ def _sharded_rewalk(key, graph: StreamingGraph, store: WalkStore, mav,
                 h_cross = h_cross + jnp.sum(
                     cont & (dest != my_shard)).astype(I32)
                 h_max = jnp.maximum(h_max, jnp.max(load)).astype(I32)
-        cur2, mine2, of = exchange_frontier(dest, nxt, spec.n_shards,
-                                            spec.slab, AXIS)
+        with trace.scope(trace.COLLECTIVE):
+            cur2, mine2, of = exchange_frontier(dest, nxt, spec.n_shards,
+                                                spec.slab, AXIS)
         if with_obs:
             return ((cur2, mine2, ovf | of, h_sent, h_cross, h_max),
                     (owner, code, emit))
         return (cur2, mine2, ovf | of), (owner, code, emit)
 
-    keys = jax.random.split(key, length)
-    init = (jnp.zeros((capacity,), U32), jnp.zeros((capacity,), bool),
-            jnp.asarray(False))
+    with trace.scope(trace.SAMPLE):
+        keys = jax.random.split(key, length)
+        init = (jnp.zeros((capacity,), U32), jnp.zeros((capacity,), bool),
+                jnp.asarray(False))
+        if with_obs:
+            z = lambda: jnp.zeros((), I32)
+            init = init + (z(), z(), z())
+            carry_out, (owners, codes, emits) = jax.lax.scan(
+                step, init, (ps, keys))
+            handoff_ovf = carry_out[2]
+        else:
+            (_, _, handoff_ovf), (owners, codes, emits) = jax.lax.scan(
+                step, init, (ps, keys))
     if with_obs:
-        z = lambda: jnp.zeros((), I32)
-        init = init + (z(), z(), z())
-        carry_out, (owners, codes, emits) = jax.lax.scan(
-            step, init, (ps, keys))
-        handoff_ovf = carry_out[2]
         obs = {"handoff_sent": carry_out[3], "handoff_cross": carry_out[4],
                "handoff_max_load": carry_out[5],
                "pmin_hist": _obs_pmin_hist(p_min, lane_valid, length)}
-    else:
-        (_, _, handoff_ovf), (owners, codes, emits) = jax.lax.scan(
-            step, init, (ps, keys))
-    owners = owners.T.reshape(-1)       # [capacity * l], lane-major
-    codes = codes.T.reshape(-1)
-    emits = emits.T.reshape(-1)
+    with trace.scope(trace.WRITE_BACK):
+        owners = owners.T.reshape(-1)       # [capacity * l], lane-major
+        codes = codes.T.reshape(-1)
+        emits = emits.T.reshape(-1)
 
-    epoch = jnp.where(emits, new_epoch, PAD_EPOCH).astype(U32)
-    owners = jnp.where(emits, owners, 0).astype(U32)
-    codes = jnp.where(emits, codes, jnp.asarray(0, U64))
+        epoch = jnp.where(emits, new_epoch, PAD_EPOCH).astype(U32)
+        owners = jnp.where(emits, owners, 0).astype(U32)
+        codes = jnp.where(emits, codes, jnp.asarray(0, U64))
 
-    # replicated slot-version bump: depends only on (walk_ids, p_min,
-    # lane_valid), NOT on which shard emitted — every shard computes the
-    # identical slot_epoch with no collective
-    slot_w = jnp.repeat(walk_ids.astype(I32), length)
-    slot_p = jnp.tile(ps, capacity)
-    slots = jnp.clip(slot_w * length + slot_p, 0,
-                     store.n_walks * length - 1)
-    emits_meta = (jnp.repeat(lane_valid, length)
-                  & (slot_p >= jnp.repeat(p_min, length)))
-    slot_epoch = store.slot_epoch.at[slots].max(
-        jnp.where(emits_meta, new_epoch, jnp.asarray(0, U32)))
+        # replicated slot-version bump: depends only on (walk_ids, p_min,
+        # lane_valid), NOT on which shard emitted — every shard computes
+        # the identical slot_epoch with no collective
+        slot_w = jnp.repeat(walk_ids.astype(I32), length)
+        slot_p = jnp.tile(ps, capacity)
+        slots = jnp.clip(slot_w * length + slot_p, 0,
+                         store.n_walks * length - 1)
+        emits_meta = (jnp.repeat(lane_valid, length)
+                      & (slot_p >= jnp.repeat(p_min, length)))
+        slot_epoch = store.slot_epoch.at[slots].max(
+            jnp.where(emits_meta, new_epoch, jnp.asarray(0, U32)))
 
-    n_aff = jnp.sum(affected)
-    block = VersionBlock(owner=owners, code=codes, epoch=epoch,
-                         slot=jnp.where(emits, slots, 0).astype(I32),
-                         n_new=jnp.sum(emits).astype(I32))
+    with trace.scope(trace.MAV):
+        n_aff = jnp.sum(affected)
+    with trace.scope(trace.WRITE_BACK):
+        block = VersionBlock(owner=owners, code=codes, epoch=epoch,
+                             slot=jnp.where(emits, slots, 0).astype(I32),
+                             n_new=jnp.sum(emits).astype(I32))
     if with_obs:
         return block, slot_epoch, n_aff, handoff_ovf, obs
     return block, slot_epoch, n_aff, handoff_ovf
@@ -347,40 +362,42 @@ def _sharded_apply_update(state: EngineState, ins_src, ins_dst, del_src,
     graph, g_ovf = _local_apply_batch(state.graph, ins_src, ins_dst,
                                       del_src, del_dst, spec, my_shard)
     store, pending = state.store, state.pending
-    new_epoch = state.epoch + jnp.asarray(1, U32)
+    with trace.scope(trace.WRITE_BACK):
+        new_epoch = state.epoch + jnp.asarray(1, U32)
 
     # MAV: local gather over owned segments (non-owned touched vertices have
     # empty local segments, so the full touched mask is correct as-is) ...
-    touched_v = jnp.zeros((store.n_vertices,), bool)
-    for arr in (ins_src, ins_dst, del_src, del_dst):
-        if arr.shape[0] > 0:
-            touched_v = touched_v.at[arr.astype(I32)].set(True)
-    g_owner, g_code, g_epoch, g_valid, total = gather_touched_segments(
-        store, touched_v, spec.mav_capacity)
-    mav_ovf = total > spec.mav_capacity
-    g_f, _ = pairing.szudzik_unpair(jnp.where(g_valid, g_code,
-                                              jnp.zeros_like(g_code)))
-    g_w = (g_f // jnp.asarray(store.length, U64)).astype(I32)
-    g_p = (g_f % jnp.asarray(store.length, U64)).astype(I32)
-    g_touched = touched_v[g_owner.astype(I32)] & g_valid
+    with trace.scope(trace.MAV):
+        touched_v = jnp.zeros((store.n_vertices,), bool)
+        for arr in (ins_src, ins_dst, del_src, del_dst):
+            if arr.shape[0] > 0:
+                touched_v = touched_v.at[arr.astype(I32)].set(True)
+        g_owner, g_code, g_epoch, g_valid, total = gather_touched_segments(
+            store, touched_v, spec.mav_capacity)
+        mav_ovf = total > spec.mav_capacity
+        g_f, _ = pairing.szudzik_unpair(jnp.where(g_valid, g_code,
+                                                  jnp.zeros_like(g_code)))
+        g_w = (g_f // jnp.asarray(store.length, U64)).astype(I32)
+        g_p = (g_f % jnp.asarray(store.length, U64)).astype(I32)
+        g_touched = touched_v[g_owner.astype(I32)] & g_valid
 
-    p_owner = pending.owner.reshape(-1)
-    p_slot = pending.slot.reshape(-1)
-    p_epoch = pending.epoch.reshape(-1)
-    p_valid = p_epoch != PAD_EPOCH
-    p_w = p_slot // store.length
-    p_p = p_slot % store.length
-    p_touched = touched_v[p_owner.astype(I32)] & p_valid
+        p_owner = pending.owner.reshape(-1)
+        p_slot = pending.slot.reshape(-1)
+        p_epoch = pending.epoch.reshape(-1)
+        p_valid = p_epoch != PAD_EPOCH
+        p_w = p_slot // store.length
+        p_p = p_slot % store.length
+        p_touched = touched_v[p_owner.astype(I32)] & p_valid
 
-    # ... then ONE pmin over the composite keys combines the shards
-    best = keyed_pmin(
-        jnp.concatenate([g_w, p_w]), jnp.concatenate([g_p, p_p]),
-        jnp.concatenate([g_owner, p_owner]),
-        jnp.concatenate([g_epoch, p_epoch]), store.slot_epoch,
-        jnp.concatenate([g_touched, p_touched]),
-        jnp.concatenate([g_valid, p_valid]),
-        store.length, store.n_walks)
-    mav = mav_from_keyed(_pmin_keys(best), store.length)
+        # ... then ONE pmin over the composite keys combines the shards
+        best = keyed_pmin(
+            jnp.concatenate([g_w, p_w]), jnp.concatenate([g_p, p_p]),
+            jnp.concatenate([g_owner, p_owner]),
+            jnp.concatenate([g_epoch, p_epoch]), store.slot_epoch,
+            jnp.concatenate([g_touched, p_touched]),
+            jnp.concatenate([g_valid, p_valid]),
+            store.length, store.n_walks)
+        mav = mav_from_keyed(_pmin_keys(best), store.length)
 
     rw = _sharded_rewalk(key, graph, store, mav, new_epoch, cfg, capacity,
                          spec, my_shard, with_obs=with_obs)
@@ -390,21 +407,22 @@ def _sharded_apply_update(state: EngineState, ins_src, ins_dst, del_src,
                    handoff_overflow=h_ovf)
     else:
         block, slot_epoch, n_aff, h_ovf = rw
-    pending = PendingBlocks(
-        owner=jax.lax.dynamic_update_index_in_dim(
-            pending.owner, block.owner, state.n_pending, 0),
-        code=jax.lax.dynamic_update_index_in_dim(
-            pending.code, block.code, state.n_pending, 0),
-        epoch=jax.lax.dynamic_update_index_in_dim(
-            pending.epoch, block.epoch, state.n_pending, 0),
-        slot=jax.lax.dynamic_update_index_in_dim(
-            pending.slot, block.slot, state.n_pending, 0))
-    n_aff = n_aff.astype(I32)
-    state = EngineState(
-        graph=graph, store=store.replace(slot_epoch=slot_epoch),
-        pending=pending, n_pending=state.n_pending + 1, epoch=new_epoch,
-        last_affected=n_aff, total_affected=state.total_affected + n_aff,
-        overflow=state.overflow | g_ovf | mav_ovf | h_ovf)
+    with trace.scope(trace.WRITE_BACK):
+        pending = PendingBlocks(
+            owner=jax.lax.dynamic_update_index_in_dim(
+                pending.owner, block.owner, state.n_pending, 0),
+            code=jax.lax.dynamic_update_index_in_dim(
+                pending.code, block.code, state.n_pending, 0),
+            epoch=jax.lax.dynamic_update_index_in_dim(
+                pending.epoch, block.epoch, state.n_pending, 0),
+            slot=jax.lax.dynamic_update_index_in_dim(
+                pending.slot, block.slot, state.n_pending, 0))
+        n_aff = n_aff.astype(I32)
+        state = EngineState(
+            graph=graph, store=store.replace(slot_epoch=slot_epoch),
+            pending=pending, n_pending=state.n_pending + 1, epoch=new_epoch,
+            last_affected=n_aff, total_affected=state.total_affected + n_aff,
+            overflow=state.overflow | g_ovf | mav_ovf | h_ovf)
     if with_obs:
         return state, obs
     return state

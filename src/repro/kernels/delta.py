@@ -156,6 +156,7 @@ def decode_chunks(packed, widths, anchors_hi, anchors_lo,
         ],
         out_specs=[pl.BlockSpec((ROWS, CHUNK), rows)] * 2,
         out_shape=[jax.ShapeDtypeStruct((c, CHUNK), U32)] * 2,
+        name="wharf_delta",
         interpret=interpret,
     )(packed, widths.reshape(-1, 1), anchors_hi.reshape(-1, 1),
       anchors_lo.reshape(-1, 1))

@@ -276,6 +276,7 @@ def factorized_next_pallas(nbrs_v, nbrs_p, prev, u_group, u_rank,
         out_specs=[scal, scal],
         out_shape=[jax.ShapeDtypeStruct((b, 1), U32),
                    jax.ShapeDtypeStruct((b, 1), U32)],
+        name="wharf_intersect",
         interpret=interpret,
     )(nbrs_v, nbrs_p, prev.reshape(-1, 1),
       u_group.astype(F32).reshape(-1, 1),

@@ -348,6 +348,7 @@ def _fused_step_pallas(store: WalkStore, epoch_grid, cidx, sc, u, nbrs_v,
             out_specs=[pl.BlockSpec((1, 1), q_map)] * 5,
         ),
         out_shape=[jax.ShapeDtypeStruct((b, 1), U32)] * 5,
+        name="wharf_megakernel",
         interpret=interpret,
     )(cidx, *inputs)
     fnv, fnf, nxt, chi, clo = out

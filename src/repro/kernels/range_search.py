@@ -122,6 +122,7 @@ def _search_slab(packed, cidx, width, ahi, alo, ft, interpret: bool):
         ),
         out_shape=[jax.ShapeDtypeStruct((q,), I32),
                    jax.ShapeDtypeStruct((q,), I32)],
+        name="wharf_findnext",
         interpret=interpret,
     )(cidx.reshape(-1), i32(width), i32(ahi), i32(alo), i32(ft), packed)
     return jax.lax.bitcast_convert_type(out_v, U32), out_f > 0

@@ -152,6 +152,7 @@ def sgns_fused(u, v_pos, v_neg, interpret: bool = False):
             jax.ShapeDtypeStruct((b, d), F32),
             jax.ShapeDtypeStruct((b, k, d), F32),
         ],
+        name="wharf_sgns",
         interpret=interpret,
     )(u, v_pos, v_neg)
     return loss[:, 0], du, dvp, dvn

@@ -137,6 +137,7 @@ def _tiled_call(kernel, a, b, interpret: bool):
         in_specs=[spec, spec],
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((m, LANES), U32)] * 2,
+        name="wharf_szudzik",
         interpret=interpret,
     )(a, b)
 

@@ -14,11 +14,39 @@ Two complementary mechanisms behind one `phase(...)` context manager:
     crashes lose at most one span and benchmarks can append concurrently.
 
 Phase taxonomy (DESIGN.md §10) — use these constants so trace consumers can
-group spans: FINDNEXT (packed-chunk decode / prefix traversal), INTERSECT
-(order-2 neighbor-window intersection), SAMPLE (SAMPLENEXT draws),
-WRITE_BACK (version-block append + slot-epoch bump), MERGE (pending
-consolidation), COLLECTIVE (cross-shard pmin / all_to_all), plus
-free-form "serve/<query>" spans from the serving layer.
+group spans: GRAPH_UPDATE (the edge batch applied to the graph), MAV (the
+touched-vertex mask, segment gather, Szudzik unpair and p_min reduction),
+FINDNEXT (packed-chunk search / order-2 prefix traversal), SAMPLE
+(SAMPLENEXT draws), INTERSECT (order-2 neighbor-window intersection, nested
+in SAMPLE), WRITE_BACK (triplet encode, version-block append, slot-epoch
+bump), MERGE (pending consolidation), COLLECTIVE (cross-shard pmin /
+all_to_all), plus free-form "serve/<query>" spans from the serving layer.
+
+Inside a jit, `scope(phase)` names the device ops `wharf/<phase>`: each op's
+HLO metadata (`op_name`, the profiler's `tf_op` stat) carries the path of
+scopes it was traced under, and the innermost `wharf/` element is its
+phase. The stream step's scope tree:
+
+  wharf/merge            the forced / eager pending merge (lax.cond branch)
+  wharf/graph_update     StreamingGraph.apply_batch
+  wharf/mav              touched mask, segment gather, unpair, p_min
+                         reduction, the affected-walk lanes
+    wharf/collective     (sharded) the two int32 pmins of the MAV keys
+  wharf/findnext         order-2 prefix traversal through the overlay
+    wharf/findnext       the packed-chunk search (kernel `wharf_findnext`)
+  wharf/sample           the rewalk's step scan: SAMPLENEXT, walker state
+    wharf/intersect      factorized order-2 groups (kernel `wharf_intersect`)
+    wharf/write_back     each step's triplet encode
+    wharf/collective     (sharded) each step's all_to_all handoff
+  wharf/write_back       version-block append, slot-epoch bump
+
+Scopes change op metadata only: the compiled instructions are the same
+with or without them. Host spans stay out of compiled programs (a jit
+traces under a fresh name stack), so `phase(...)` around a jitted call
+names only the host's side of it: `WalkEngine.run_stream` opens
+`wharf/run_stream` with children `wharf/stage` (argument staging, key
+split) and `wharf/dispatch` (the jitted call); `WalkEngine._update` and
+`WalkEngine.merge` open `wharf/update` and `wharf/consolidate`.
 
 A span measures HOST wall time between enter and exit. Around a jitted
 call that includes dispatch plus however much device work the call blocks
@@ -38,13 +66,17 @@ from typing import Optional
 import jax
 
 # in-jit engine phases (named_scope spelling: "wharf/<phase>")
+PREFIX = "wharf/"
+GRAPH_UPDATE = "graph_update"
+MAV = "mav"
 FINDNEXT = "findnext"
 INTERSECT = "intersect"
 SAMPLE = "sample"
 WRITE_BACK = "write_back"
 MERGE = "merge"
 COLLECTIVE = "collective"
-PHASES = (FINDNEXT, INTERSECT, SAMPLE, WRITE_BACK, MERGE, COLLECTIVE)
+PHASES = (GRAPH_UPDATE, MAV, FINDNEXT, INTERSECT, SAMPLE, WRITE_BACK, MERGE,
+          COLLECTIVE)
 
 
 class TraceLog:
@@ -119,6 +151,14 @@ def uninstall() -> None:
 
 def active() -> Optional[TraceLog]:
     return _LOG
+
+
+def scope(name: str):
+    """`jax.named_scope("wharf/<name>")` for one of the PHASES: the device
+    ops traced inside carry the phase in their metadata."""
+    if name not in PHASES:
+        raise ValueError(f"unknown engine phase {name!r}; one of {PHASES}")
+    return jax.named_scope(PREFIX + name)
 
 
 @contextlib.contextmanager

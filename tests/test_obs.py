@@ -151,6 +151,143 @@ def test_metrics_scope_in_compiled_executables():
     assert "obs_metrics" in on
 
 
+# ------------------------------------------ phase scopes (DESIGN.md §10)
+
+# the stream step's own ops, as the compiled metadata names them: under the
+# scan body's call, and not the call boundary itself (XLA attributes the
+# broadcasts it makes at an inlined call to the call: `.../jit(_rewalk)`)
+STEP_PATH = "jit(_run_stream_body)/while/body/closed_call/"
+SCOPED_KINDS = ("fusion", "custom-call", "sort", "scatter", "gather")
+STEP_PHASES = {
+    "order1": {"graph_update", "mav", "sample", "write_back", "merge"},
+    "order2-factorized": {"graph_update", "mav", "findnext", "sample",
+                          "intersect", "write_back", "merge"},
+}
+STEP_MODELS = {
+    "order1": WalkModel(),
+    "order2-factorized": WalkModel(order=2, p=0.5, q=2.0,
+                                   sampler="factorized", dmax=16),
+}
+
+
+def _step_ops(text: str):
+    """(kind, op_name) of each fusion, custom-call, sort, scatter and
+    gather instruction the stream step traced, from compiled HLO text."""
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = .*? ([\w-]+)\(", line)
+        if not m or m.group(1) not in SCOPED_KINDS:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        name = name.group(1) if name else ""
+        last = name.rsplit("/", 1)[-1]
+        if name.startswith(STEP_PATH) and not last.startswith("jit("):
+            yield m.group(1), name
+
+
+@pytest.mark.parametrize("model", list(STEP_MODELS))
+def test_every_stream_step_op_carries_a_wharf_scope(model):
+    """The compiled stream step names every phase of section 1 in its op
+    metadata, and every fusion, custom call, sort, scatter and gather the
+    step traces carries a `wharf/` scope: a trace can charge each op's
+    device time to a phase (benchmarks/chip/xplane_ops.py)."""
+    cfg = WalkConfig(n_walks_per_vertex=2, length=8,
+                     model=STEP_MODELS[model])
+    g, store = make_graph_store(cfg)
+    i_s, i_d, d_s, d_d = make_stream(n_batches=2)
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    state = EngineState.create(g, store, 1, 32 * cfg.length)
+    text = _run_stream_jit.lower(
+        state, keys, i_s, i_d, d_s, d_d, cfg=cfg, capacity=32,
+        mav_capacity=store.size, max_pending=1, merge_policy="on-demand",
+        merge_impl="interleave").compile().as_text()
+    ops = list(_step_ops(text))
+    assert len(ops) > 100
+    unscoped = [(k, n) for k, n in ops if "wharf/" not in n]
+    assert not unscoped, unscoped[:5]
+    phases = {re.findall(r"wharf/(\w+)", n)[-1] for _, n in ops}
+    assert phases == STEP_PHASES[model]
+    assert phases <= set(obs_trace.PHASES)
+
+
+def test_scope_and_phase_spell_names_as_documented():
+    assert obs_trace.PHASES == ("graph_update", "mav", "findnext",
+                                "intersect", "sample", "write_back",
+                                "merge", "collective")
+    assert (obs_trace.GRAPH_UPDATE, obs_trace.MAV) == ("graph_update", "mav")
+
+    def f(x):
+        with obs_trace.scope(obs_trace.MAV):
+            return x + 1
+
+    text = jax.jit(f).lower(jnp.ones(3)).as_text(debug_info=True)
+    assert '"jit(f)/wharf/mav/add"' in text
+    with pytest.raises(ValueError, match="unknown engine phase"):
+        obs_trace.scope("wharf/mav")
+    with pytest.raises(ValueError, match="unknown engine phase"):
+        obs_trace.scope("rewalk")
+
+
+def test_engine_host_spans_name_stage_and_dispatch(tmp_path):
+    """run_stream opens wharf/run_stream around wharf/stage and
+    wharf/dispatch; the per-batch driver and merge open wharf/update and
+    wharf/consolidate. The spans stay on the host: a second stream under
+    them compiles nothing, and no host span name reaches the program."""
+    cfg = WalkConfig(n_walks_per_vertex=1, length=4)
+    g, store = make_graph_store(cfg)
+    i_s, i_d, d_s, d_d = make_stream(n_batches=2)
+    eng = WalkEngine(graph=g, store=store, cfg=cfg, rewalk_capacity=CAP,
+                     max_pending=MAX_PENDING)
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    path = str(tmp_path / "spans.jsonl")
+    obs_trace.install(path)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        eng.run_stream(jax.random.PRNGKey(1), i_s, i_d, d_s, d_d)
+        jax.block_until_ready(eng.state)
+        first = len(compiles)
+        eng.run_stream(jax.random.PRNGKey(2), i_s, i_d, d_s, d_d)
+        jax.block_until_ready(eng.state)
+        assert len(compiles) == first
+        eng.insert_edges(jax.random.PRNGKey(3), i_s[0], i_d[0])
+        eng.merge()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+        obs_trace.uninstall()
+    spans = obs_trace.read_spans(path)
+    names = [e["name"] for e in spans]
+    # a span is written when it closes: children before their parent
+    assert names[:6] == ["wharf/stage", "wharf/dispatch", "wharf/run_stream"
+                         ] * 2
+    assert spans[2]["args"] == {"n_batches": 2}
+    # four batches filled the pending buffer: the update merges first
+    assert names[6:] == ["wharf/consolidate", "wharf/update",
+                         "wharf/consolidate"]
+    by = {e["name"]: e for e in spans[:3]}
+    run = by["wharf/run_stream"]
+    for child in ("wharf/stage", "wharf/dispatch"):
+        c = by[child]
+        assert run["ts"] <= c["ts"] and (c["ts"] + c["dur"]
+                                         <= run["ts"] + run["dur"] + 1e-3)
+    state = EngineState.create(g, store, MAX_PENDING, CAP * cfg.length)
+    keys = jax.random.split(jax.random.PRNGKey(3), 2)
+    kw = dict(cfg=cfg, capacity=CAP, mav_capacity=store.size,
+              max_pending=MAX_PENDING, merge_policy="on-demand",
+              merge_impl="interleave")
+    with obs_trace.phase("wharf/run_stream"), obs_trace.phase(
+            "wharf/dispatch"):
+        inside = _run_stream_jit.lower(state, keys, i_s, i_d, d_s, d_d,
+                                       **kw).as_text(debug_info=True)
+    assert "wharf/dispatch" not in inside
+    assert "wharf/run_stream" not in inside
+    assert inside == _run_stream_jit.lower(
+        state, keys, i_s, i_d, d_s, d_d, **kw).as_text(debug_info=True)
+
+
 # ------------------------------------------------- (b) + (c) single host
 
 

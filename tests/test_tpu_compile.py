@@ -12,6 +12,9 @@ only one process may load the TPU library, and every test worker imports
 this file. Code that asks `jax.default_backend()` sees the CPU here; tests
 that need the TPU branch of a registry steer it with monkeypatch.
 """
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -34,6 +37,10 @@ SMOKE_C = SMOKE_T // delta.CHUNK
 SMOKE_K = 8
 ORDER2_B = (1 << smoke.SMALL_LOG2_N) * smoke.N_WALKS_PER_VERTEX
 SGNS_B, SGNS_K, SGNS_D = 1 << 16, 4, 128
+# the update cells' order-2 traversal: node2vec-er's store (2^10 vertices x
+# 10 walks x 80 codes, 128 codes a chunk) searched in slabs of 256 queries
+CELL_C = (1 << 10) * 10 * 80 // delta.CHUNK
+CELL_SLAB = range_search.SLAB
 
 
 @pytest.fixture(scope="module")
@@ -78,33 +85,57 @@ def compile_for(one_chip, no_persistent_cache):
 
 
 def test_range_search_compiles_at_smoke_size(compile_for):
-    compile_for(range_search.find_next_packed,
-                ((SMOKE_C, delta.WORDS), U32), ((SMOKE_C,), U32),
-                ((SMOKE_C,), U32), ((SMOKE_C,), U32),
-                ((SMOKE_Q, SMOKE_K), I32), ((SMOKE_Q,), U32))
+    text = compile_for(range_search.find_next_packed,
+                       ((SMOKE_C, delta.WORDS), U32), ((SMOKE_C,), U32),
+                       ((SMOKE_C,), U32), ((SMOKE_C,), U32),
+                       ((SMOKE_Q, SMOKE_K), I32), ((SMOKE_Q,), U32))
+    assert "wharf_findnext" in text
+
+
+def test_named_findnext_kernel_is_found_by_the_benchmark(compile_for):
+    """The kernel named wharf_findnext keeps the signature the benchmark's
+    roofline.findnext_queries tells FINDNEXT apart by: at the update cells'
+    slab, the compiled call answers 256 queries."""
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]
+                           / "benchmarks" / "chip"))
+    import roofline
+    text = compile_for(range_search.find_next_packed,
+                       ((CELL_C, delta.WORDS), U32), ((CELL_C,), U32),
+                       ((CELL_C,), U32), ((CELL_C,), U32),
+                       ((CELL_SLAB, SMOKE_K), I32), ((CELL_SLAB,), U32))
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and calls[0].startswith("%wharf_findnext")
+    assert roofline.findnext_queries(calls[0]) == CELL_SLAB == 256
 
 
 def test_intersect_compiles_at_dmax_128(compile_for):
-    compile_for(lambda *a: intersect.factorized_next_pallas(
+    text = compile_for(lambda *a: intersect.factorized_next_pallas(
         *a, inv_p=2.0, inv_q=0.5),
         ((ORDER2_B, 128), U32), ((ORDER2_B, 128), U32), ((ORDER2_B,), U32),
         ((ORDER2_B,), F32), ((ORDER2_B,), F32))
+    assert "wharf_intersect" in text
 
 
 def test_sgns_compiles_at_maintainer_width(compile_for):
-    compile_for(sgns.sgns_fused, ((SGNS_B, SGNS_D), F32),
-                ((SGNS_B, SGNS_D), F32), ((SGNS_B, SGNS_K, SGNS_D), F32))
+    text = compile_for(sgns.sgns_fused, ((SGNS_B, SGNS_D), F32),
+                       ((SGNS_B, SGNS_D), F32),
+                       ((SGNS_B, SGNS_K, SGNS_D), F32))
+    assert "wharf_sgns" in text
 
 
 @pytest.mark.parametrize("kernel", ["decode", "pair", "unpair"])
 def test_standalone_kernels_compile(compile_for, kernel):
     c = 4096
     if kernel == "decode":
-        compile_for(delta.decode_chunks, ((c, delta.WORDS), U32),
-                    ((c,), U32), ((c,), U32), ((c,), U32))
+        text = compile_for(delta.decode_chunks, ((c, delta.WORDS), U32),
+                           ((c,), U32), ((c,), U32), ((c,), U32))
+        assert "wharf_delta" in text
     else:
         fn = szudzik.pair_tiles if kernel == "pair" else szudzik.unpair_tiles
-        compile_for(fn, ((c, szudzik.LANES), U32), ((c, szudzik.LANES), U32))
+        text = compile_for(fn, ((c, szudzik.LANES), U32),
+                           ((c, szudzik.LANES), U32))
+        assert "wharf_szudzik" in text
 
 
 def test_tpu_auto_paths_pad_instead_of_falling_back(compile_for,
