@@ -53,6 +53,18 @@ I32 = jnp.int32
 PAD_EPOCH = jnp.asarray(0xFFFFFFFF, U32)
 
 
+def _query_arrays(v, w, p):
+    """FINDNEXT query operands as 1-d arrays: v u32, w and p u64."""
+    return (jnp.atleast_1d(jnp.asarray(v, U32)),
+            jnp.atleast_1d(jnp.asarray(w, U64)),
+            jnp.atleast_1d(jnp.asarray(p, U64)))
+
+
+def _last_chunk(lo, hi):
+    """The chunk of [lo, hi)'s last code (lo's chunk when it is empty)."""
+    return jnp.maximum(hi - 1, lo) // CHUNK
+
+
 @jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class WalkStore:
@@ -157,22 +169,17 @@ class WalkStore:
         reference scan, and every packed hit is verified against the
         authoritative code/epoch arrays, which restores the slot-epoch
         liveness check so stale pre-merge versions are skipped exactly as
-        in "xla-ref". `window` (chunks per query) applies to the
-        pallas/pallas-interpret kernels only; the "interpret" backend uses
-        a fixed 2-chunk window with a MAX_CANDIDATES output-sensitive cap.
+        in "xla-ref". `window` caps the chunks per query of the
+        pallas/pallas-interpret kernels, which decode only the chunks the
+        query's candidate range spans (`_kernel_window`); the "interpret"
+        backend uses a fixed 2-chunk window with a MAX_CANDIDATES
+        output-sensitive cap.
         """
         backend = packed_store.resolve_backend(backend)
         if self.n_walks * self.length > 0xFFFFFFFF:
             backend = "xla-ref"  # kernel f-match is u32; huge corpora scan
-        v = jnp.atleast_1d(jnp.asarray(v, U32))
-        w64 = jnp.atleast_1d(jnp.asarray(w, U64))
-        p64 = jnp.atleast_1d(jnp.asarray(p, U64))
-        f = pairing.pack_wp(w64, p64, self.length)
-        lb, ub = pairing.search_range(f, self.vmin[v], self.vmax[v])
-        seg_lo = self.offsets[v]
-        seg_hi = self.offsets[v + jnp.asarray(1, U32)]
-        lo = seg_searchsorted(self.code, seg_lo, seg_hi, lb, side="left")
-        hi = seg_searchsorted(self.code, seg_lo, seg_hi, ub, side="right")
+        v, w64, p64 = _query_arrays(v, w, p)
+        f, lo, hi, seg_lo, seg_hi = self._candidate_range(v, w64, p64)
         slot = (w64 * jnp.asarray(self.length, U64) + p64).astype(I32)
         want_epoch = self.slot_epoch[slot]
 
@@ -203,13 +210,11 @@ class WalkStore:
             over = (hi - lo) > wmax
         else:  # "pallas" / "pallas-interpret": the packed-chunk kernel
             k = window or packed_store.get_default_window()
-            c1 = jnp.maximum(hi - 1, lo) // CHUNK
-            cidx = jnp.clip(c0[:, None] + jnp.arange(k, dtype=I32)[None],
-                            0, self.n_chunks - 1)
+            cidx = self._kernel_window(lo, hi, k)
             v_k, f_k = packed_store.packed_search(
                 self.packed, self.widths, self.anchors_hi, self.anchors_lo,
                 cidx, f, backend)
-            over = (hi > lo) & ((c1 - c0) >= k)
+            over = (hi > lo) & ((_last_chunk(lo, hi) - c0) >= k)
         # verification against the authoritative arrays: the hit must sit in
         # v's segment AND carry the slot's live epoch (mid-update liveness)
         tgt = pairing.szudzik_pair(f, v_k.astype(U64))
@@ -224,6 +229,38 @@ class WalkStore:
                                         want_epoch)
         return (jnp.where(over, o_out, out).astype(U32),
                 jnp.where(over, o_found, found))
+
+    def _candidate_range(self, v, w64, p64):
+        """The query's f, v's segment [seg_lo, seg_hi) and the candidate
+        range [lo, hi) in it: the codes in [<f, vmin[v]>, <f, vmax[v]>]
+        (§5.1), which hold every code of v's segment whose first component
+        is f."""
+        f = pairing.pack_wp(w64, p64, self.length)
+        lb, ub = pairing.search_range(f, self.vmin[v], self.vmax[v])
+        seg_lo = self.offsets[v]
+        seg_hi = self.offsets[v + jnp.asarray(1, U32)]
+        lo = seg_searchsorted(self.code, seg_lo, seg_hi, lb, side="left")
+        hi = seg_searchsorted(self.code, seg_lo, seg_hi, ub, side="right")
+        return f, lo, hi, seg_lo, seg_hi
+
+    def _kernel_window(self, lo, hi, k: int):
+        """The packed kernel's k candidate chunks per query: the chunks
+        c0..c1 that [lo, hi) spans, the last repeated to fill the window
+        (the kernel decodes a repeated chunk once), i32 [Q, k]."""
+        c0 = lo // CHUNK
+        idx = jnp.minimum(c0[:, None] + jnp.arange(k, dtype=I32)[None],
+                          _last_chunk(lo, hi)[:, None])
+        return jnp.clip(idx, 0, self.n_chunks - 1)
+
+    def findnext_chunks(self, v, w, p, window: Optional[int] = None):
+        """Distinct chunks the packed kernel decodes for each FINDNEXT
+        query (v, w, p), i32 [Q]: the chunks its candidate range spans, at
+        most `window`. The FINDNEXT engagement counter of StreamMetrics."""
+        v, w64, p64 = _query_arrays(v, w, p)
+        _, lo, hi, _, _ = self._candidate_range(v, w64, p64)
+        cidx = self._kernel_window(
+            lo, hi, window or packed_store.get_default_window())
+        return 1 + jnp.sum(cidx[:, 1:] != cidx[:, :-1], axis=1, dtype=I32)
 
     def _scan_ref(self, lo, hi, f, want_epoch,
                   unpair=pairing.szudzik_unpair):

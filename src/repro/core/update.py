@@ -71,6 +71,11 @@ class UpdateAux(NamedTuple):
     walk_ids: jax.Array    # uint32 [capacity] compacted affected walk ids
     lane_valid: jax.Array  # bool   [capacity] lanes < |affected|
     p_min: jax.Array       # int32  [capacity] first re-sampled position
+    # with cfg.metrics, order 2 (unfused): the prefix traversal's FINDNEXT
+    # queries and the distinct chunks the packed kernel decodes for them,
+    # int32 []; None otherwise (obs/metrics.py)
+    findnext_queries: Optional[jax.Array] = None
+    findnext_chunks: Optional[jax.Array] = None
 
 
 class PendingBlocks(NamedTuple):
@@ -751,6 +756,7 @@ def _rewalk(key, graph: StreamingGraph, store: WalkStore,
     with trace.scope(trace.SAMPLE):
         ps = jnp.arange(length, dtype=I32)
 
+    counters = ()
     req = (cfg.megakernel if cfg.megakernel != "auto"
            else megakernel.default_backend_request())
     backend = megakernel.resolve_backend(req)
@@ -777,6 +783,9 @@ def _rewalk(key, graph: StreamingGraph, store: WalkStore,
                 prefix = view.traverse(walk_ids, start, length - 1)
                 prev0 = prefix[jnp.arange(capacity),
                                jnp.maximum(p_min - 1, 0)]
+            if cfg.metrics:
+                from repro.obs.metrics import findnext_engagement
+                counters = findnext_engagement(store, walk_ids, prefix)
         else:
             prev0 = v_at_pmin
 
@@ -828,7 +837,7 @@ def _rewalk(key, graph: StreamingGraph, store: WalkStore,
         block = VersionBlock(owner=owners, code=codes, epoch=epoch,
                              slot=jnp.where(emits, slots, 0).astype(I32),
                              n_new=jnp.sum(emits).astype(I32))
-    aux = UpdateAux(walk_ids=walk_ids, lane_valid=lane_valid, p_min=p_min)
+    aux = UpdateAux(walk_ids, lane_valid, p_min, *counters)
     return block, slot_epoch, n_aff, aux
 
 

@@ -4,23 +4,26 @@ The paper's output-sensitive range search maps to TPU as a paged-attention
 style kernel: XLA computes each query's candidate chunk window [via
 searchsorted on the O(1) chunk heads — the §5.2 head optimization], then the
 kernel walks the K candidate chunks per query with their indices delivered
-through *scalar prefetch* (the BlockSpec index_map selects which compressed
-chunk block to DMA per grid step — block-table indirection):
+through *scalar prefetch* (block-table indirection):
 
-  grid = (Q, K); step (q, k):
-    DMA the 8-row block of packed [C, WORDS] holding chunk cidx[q,k] (Mosaic
-    blocks must be (8, 128)-aligned) and select its row
-    decode the chunk         (FOR bit-unpack + 64-bit limb prefix sum)
-    unpair codes             (emulated-u64 Szudzik inverse, isqrt-free:
-                              f == target test only needs pair(f, v) forms —
-                              full unpair used for exactness)
-    match f == f_target[q]   -> accumulate (v_next, found) into out[q]
+  grid = (Q,); step q:
+    the 8-row block of packed [C, WORDS] holding the query's first chunk
+    arrives by the BlockSpec's index map (pipelined with the previous
+    query; Mosaic blocks must be (8, 128)-aligned); a later candidate in
+    another block is fetched by hand from HBM
+    for each DISTINCT candidate chunk, in order, until the first hit:
+      select its row, decode it (FOR bit-unpack + 64-bit limb prefix sum)
+      unpair codes       (emulated-u64 Szudzik inverse)
+      match f == f_target[q] -> (v_next, found) into out[q]
 
-Chunks outside every query's candidate block are never fetched — the
-candidate window IS the paper's chunk-skip, expressed as DMA avoidance. The
-per-query scalars (candidate ids, their widths and anchors, the f target)
-ride scalar prefetch in SMEM and the outputs are SMEM vectors, so no block
-is narrower than a tile; every value in the kernel is 32-bit under x64.
+A candidate equal to the one before it is not decoded again: the store
+(WalkStore.find_next) clamps each window to the chunks c0..c1 its
+candidate range spans, so a range inside one chunk decodes one chunk
+however wide the window — the paper's §5.3 output sensitivity. Chunks
+outside every query's candidates are never fetched. The per-query scalars
+(candidate ids, their widths and anchors, the f target) ride scalar
+prefetch in SMEM and the outputs are SMEM vectors, so no block is narrower
+than a tile; every value in the kernel is 32-bit under x64.
 """
 from __future__ import annotations
 
@@ -58,54 +61,90 @@ def _umax_bits(x):
     return m ^ flip
 
 
-def _search_kernel(cidx_ref, width_ref, ahi_ref, alo_ref, ft_ref, packed_ref,
-                   vout_ref, found_ref, *, k_total):
-    q = pl.program_id(0)
-    k = pl.program_id(1)
-    j = q * k_total + k
-    c = cidx_ref[j]
-    # the block holds the 8 packed rows around chunk c; select row c % 8
-    # (a masked int32 sum: Mosaic reduces only signed integers)
-    rows = jax.lax.bitcast_convert_type(packed_ref[...], I32)
+def _match_row(rows, c, width, a_hi, a_lo, f_target):
+    """Decode chunk c out of its 8-row block `rows` (u32 [ROWS, WORDS]) and
+    match it against the query: (hits, max matching v) as int32 scalars."""
+    # select row c % 8 (a masked int32 sum: Mosaic reduces only signed
+    # integers)
+    rows = jax.lax.bitcast_convert_type(rows, I32)
     sub = jax.lax.broadcasted_iota(I32, rows.shape, 0)
     mine = jnp.where(sub == (c & (ROWS - 1)), rows, jnp.zeros_like(rows))
     row = jax.lax.bitcast_convert_type(
         jnp.sum(mine, axis=0, keepdims=True, dtype=I32), U32)  # (1, WORDS)
 
-    def scalar(ref, i):
-        return jax.lax.bitcast_convert_type(
-            jnp.full((1, 1), ref[i], I32), U32)
+    def scalar(x):
+        return jax.lax.bitcast_convert_type(jnp.full((1, 1), x, I32), U32)
 
-    hi, lo = decode_block(row, scalar(width_ref, j), scalar(ahi_ref, j),
-                          scalar(alo_ref, j))
+    hi, lo = decode_block(row, scalar(width), scalar(a_hi), scalar(a_lo))
     f, v = szudzik_unpair_math(hi, lo)
-    hit = f == scalar(ft_ref, q)
+    hit = f == scalar(f_target)
     n_hit = _row_reduce(functools.partial(jnp.sum, dtype=I32),
                         hit.astype(I32))
-    val = _umax_bits(jnp.where(hit, v, jnp.zeros_like(v)))
+    return n_hit, _umax_bits(jnp.where(hit, v, jnp.zeros_like(v)))
 
-    @pl.when(k == 0)
-    def _init():
-        vout_ref[q] = jnp.int32(0)
-        found_ref[q] = jnp.int32(0)
 
-    # first hitting chunk wins (max matching v within it)
-    @pl.when((n_hit > 0) & (found_ref[q] == 0))
-    def _take():
-        vout_ref[q] = val
-        found_ref[q] = jnp.int32(1)
+def _search_kernel(cidx_ref, width_ref, ahi_ref, alo_ref, ft_ref, block_ref,
+                   packed_hbm, vout_ref, found_ref, spill_ref, sem, *,
+                   k_total):
+    """One query: its candidate chunks in order, each decoded once. The
+    first chunk's block arrives through the pipelined BlockSpec; a chunk in
+    another block is fetched by hand into `spill_ref`."""
+    q = pl.program_id(0)
+    base = q * k_total
+    b0 = jax.lax.div(cidx_ref[base], np.int32(ROWS))
+    vout_ref[q] = jnp.int32(0)
+    found_ref[q] = jnp.int32(0)
+
+    def step(kk):
+        j = base + kk
+        c = cidx_ref[j]
+        # a chunk repeated from the previous candidate (a window clamped to
+        # the range's last chunk) is not decoded again, nor any chunk after
+        # the first hit
+        prev = cidx_ref[j - jnp.minimum(kk, np.int32(1))]
+        fresh = (kk == np.int32(0)) | (c != prev)
+
+        @pl.when(fresh & (found_ref[q] == 0))
+        def _search():
+            b = jax.lax.div(c, np.int32(ROWS))
+
+            @pl.when(b != b0)
+            def _fetch():
+                cp = pltpu.make_async_copy(
+                    packed_hbm.at[pl.ds(pl.multiple_of(b * ROWS, ROWS),
+                                        ROWS)], spill_ref, sem)
+                cp.start()
+                cp.wait()
+
+            rows = jnp.where(b == b0, block_ref[...], spill_ref[...])
+            n_hit, val = _match_row(rows, c, width_ref[j], ahi_ref[j],
+                                    alo_ref[j], ft_ref[q])
+
+            # first hitting chunk wins (max matching v within it)
+            @pl.when(n_hit > 0)
+            def _take():
+                vout_ref[q] = val
+                found_ref[q] = jnp.int32(1)
+
+        return kk + np.int32(1)
+
+    # a while loop keeps the counter int32 (a fori_loop with static bounds
+    # becomes a scan over a 64-bit counter under x64, which Mosaic rejects)
+    jax.lax.while_loop(lambda kk: kk < k_total, step, np.int32(0))
 
 
 def _search_slab(packed, cidx, width, ahi, alo, ft, interpret: bool):
-    """One pallas_call over a slab of queries: grid (Q, K); all per-query
+    """One pallas_call over a slab of queries: grid (Q,); all per-query
     scalars (candidate chunk ids, their widths/anchors, the f targets) ride
-    scalar prefetch, the chunk block is picked by the index map."""
+    scalar prefetch, the block of each query's first chunk is picked by the
+    index map and `packed` is passed a second time, left in HBM, for the
+    rare candidate in another block."""
     q, k = cidx.shape
 
     # index maps return int32 explicitly: under x64 a Python 0 (or a
     # jnp.floor_divide by one) traces as int64, which Mosaic rejects
-    def chunk_map(qi, ki, cidx_ref, *_):
-        return jax.lax.div(cidx_ref[qi * k + ki], np.int32(ROWS)), np.int32(0)
+    def first_block(qi, cidx_ref, *_):
+        return jax.lax.div(cidx_ref[qi * k], np.int32(ROWS)), np.int32(0)
 
     def i32(x):
         return jax.lax.bitcast_convert_type(x.reshape(-1), I32)
@@ -116,15 +155,19 @@ def _search_slab(packed, cidx, width, ahi, alo, ft, interpret: bool):
         functools.partial(_search_kernel, k_total=k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
-            grid=(q, k),
-            in_specs=[pl.BlockSpec((ROWS, WORDS), chunk_map)],
+            grid=(q,),
+            in_specs=[pl.BlockSpec((ROWS, WORDS), first_block),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[smem, smem],
+            scratch_shapes=[pltpu.VMEM((ROWS, WORDS), U32),
+                            pltpu.SemaphoreType.DMA(())],
         ),
         out_shape=[jax.ShapeDtypeStruct((q,), I32),
                    jax.ShapeDtypeStruct((q,), I32)],
         name="wharf_findnext",
         interpret=interpret,
-    )(cidx.reshape(-1), i32(width), i32(ahi), i32(alo), i32(ft), packed)
+    )(cidx.reshape(-1), i32(width), i32(ahi), i32(alo), i32(ft), packed,
+      packed)
     return jax.lax.bitcast_convert_type(out_v, U32), out_f > 0
 
 

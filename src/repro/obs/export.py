@@ -102,7 +102,9 @@ def summary(m: StreamMetrics, serve: Optional[dict] = None,
         "pending": {"high_water_mark": int(m.pending_hwm)},
         "merges": {"forced": int(m.merges_forced),
                    "eager": int(m.merges_eager)},
-        "order2": {"deg_fallback_lane_steps": int(m.deg_fallback_lanes)},
+        "order2": {"deg_fallback_lane_steps": int(m.deg_fallback_lanes),
+                   "findnext_queries": int(m.findnext_queries),
+                   "findnext_chunks": int(m.findnext_chunks)},
         "handoff": {
             "sent_total": sent,
             "cross_shard_total": int(m.handoff_cross),
@@ -125,8 +127,9 @@ def summary(m: StreamMetrics, serve: Optional[dict] = None,
 def upgrade_summary(s: dict) -> dict:
     """Normalize a v1 OR v2 summary dict to the v2 shape (round-trip
     contract: the schema is append-only, so a v1 cell upgrades by zero-
-    filling the sections it predates and nothing else changes; a v2 cell
-    passes through unchanged). Raises on unknown schema versions."""
+    filling the sections and counters it predates and nothing else
+    changes; a current v2 cell passes through unchanged). Raises on
+    unknown schema versions."""
     v = s.get("schema")
     if v not in (1, SCHEMA):
         raise ValueError(f"unknown counters schema {v!r}; "
@@ -135,6 +138,8 @@ def upgrade_summary(s: dict) -> dict:
     out["schema"] = SCHEMA
     if "staleness" not in out:
         out["staleness"] = _staleness_summary(StalenessMetrics.empty())
+    out["order2"] = {"findnext_queries": 0, "findnext_chunks": 0,
+                     **out["order2"]}
     return out
 
 
@@ -195,6 +200,10 @@ def to_prometheus(m, serve: Optional[dict] = None, slo: Optional[dict] = None,
     counter("order2_deg_fallback_lane_steps_total",
             s["order2"]["deg_fallback_lane_steps"],
             "deg>dmax rejection-fallback sampling lane-steps")
+    counter("order2_findnext_queries_total", s["order2"]["findnext_queries"],
+            "order-2 prefix-traversal FINDNEXT queries")
+    counter("order2_findnext_chunks_total", s["order2"]["findnext_chunks"],
+            "packed chunks the FINDNEXT kernel decoded for them")
     counter("handoff_lanes_sent_total", s["handoff"]["sent_total"],
             "frontier lanes routed through all_to_all")
     counter("handoff_lanes_cross_shard_total",

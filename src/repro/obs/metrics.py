@@ -34,6 +34,12 @@ Counter semantics (what the paper's rate claims need):
     version block, so every rewalk backend (unfused or megakernel) is
     covered without sampler plumbing; the deg(prev)-only trigger is not
     counted (documented lower bound).
+  * ``findnext_queries`` / ``findnext_chunks`` — order-2 unfused rewalks
+    only: the prefix traversal's FINDNEXT queries (every lane, positions
+    0..l-2) and the distinct chunks the packed kernel decodes for them
+    (each query's candidate-range span, at most the window). Their ratio
+    is the chunks per query; computed post-hoc from the traversal's path,
+    so it counts the kernel's window whatever backend ran.
   * ``handoff_sent`` / ``handoff_cross`` / ``handoff_max_load`` — sharded
     engine only, per shard: lanes routed through the `all_to_all` frontier
     exchange (sent = all continuing lanes incl. the self-slab row, cross =
@@ -86,6 +92,8 @@ class StreamMetrics:
     merges_forced: jax.Array        # i32 [] pending-full in-scan merges
     merges_eager: jax.Array         # i32 [] eager-policy in-scan merges
     deg_fallback_lanes: jax.Array   # i32 [] deg>dmax fallback lane-steps
+    findnext_queries: jax.Array     # i32 [] order-2 traversal FINDNEXTs
+    findnext_chunks: jax.Array      # i32 [] chunks decoded for them
     handoff_sent: jax.Array         # i32 [] lanes routed (this shard)
     handoff_cross: jax.Array        # i32 [] lanes leaving this shard
     handoff_max_load: jax.Array     # i32 [] max lanes to one dest per step
@@ -105,7 +113,8 @@ class StreamMetrics:
             n_steps=z(), affected_total=z(), affected_max=z(),
             pmin_hist=jnp.zeros((PMIN_BUCKETS,), I32),
             pending_hwm=z(), merges_forced=z(), merges_eager=z(),
-            deg_fallback_lanes=z(), handoff_sent=z(), handoff_cross=z(),
+            deg_fallback_lanes=z(), findnext_queries=z(),
+            findnext_chunks=z(), handoff_sent=z(), handoff_cross=z(),
             handoff_max_load=z(),
             overflow_first_epoch=jnp.full((len(OVERFLOW_SOURCES),), NEVER,
                                           U32),
@@ -140,6 +149,22 @@ def deg_fallback_count(graph, block_owner, block_epoch, length: int, model):
     deg = graph.degree(jnp.clip(block_owner.astype(I32), 0,
                                 graph.n_vertices - 1))
     return jnp.sum(emitted & (deg > model.dmax)).astype(I32)
+
+
+def findnext_engagement(store, walk_ids, prefix):
+    """(queries, chunks) of one order-2 prefix traversal, i32 scalars.
+
+    `prefix` is the [capacity, l] path `store.traverse` (or the overlay's)
+    returned for `walk_ids`: step p asked FINDNEXT (prefix[:, p], w, p)
+    for p in 0..l-2, and the base store's packed kernel decoded
+    `store.findnext_chunks` distinct chunks for each."""
+    with jax.named_scope("obs_metrics"):
+        cap, length = prefix.shape
+        v = prefix[:, :-1].reshape(-1)
+        w = jnp.repeat(walk_ids, length - 1)
+        p = jnp.tile(jnp.arange(length - 1, dtype=U32), cap)
+        chunks = jnp.sum(store.findnext_chunks(v, w, p), dtype=I32)
+        return jnp.asarray(v.shape[0], I32), chunks
 
 
 def record_overflow(m: StreamMetrics, source: int, tripped, epoch
@@ -189,6 +214,10 @@ def record_engine_step(m: StreamMetrics, state, aux, block_row, forced_merge,
             deg_fallback_lanes=m.deg_fallback_lanes + deg_fallback_count(
                 state.graph, owner, epoch_col, length, cfg.model),
             staleness=st)
+        if aux.findnext_queries is not None:
+            m = m.replace(
+                findnext_queries=m.findnext_queries + aux.findnext_queries,
+                findnext_chunks=m.findnext_chunks + aux.findnext_chunks)
     return record_overflow(m, OVF_MAV, state.overflow & ~overflow_before,
                            state.epoch)
 

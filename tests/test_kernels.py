@@ -1,5 +1,7 @@
 """Per-kernel interpret=True validation vs ref.py oracles: shape/dtype sweeps
 + hypothesis property tests (exactness for integer kernels, allclose for f32)."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -102,8 +104,25 @@ def test_delta_compression_wins_on_clustered_ids():
 # ------------------------------------------------------------ range search
 
 
+def _unpair_py(z: int):
+    """Szudzik inverse in Python integers (independent of the kernels)."""
+    s = math.isqrt(z)
+    return (z - s * s, s) if z - s * s < s else (s, z - s * s - s)
+
+
+def _scan_range_py(codes, lo, hi, f):
+    """The scalar reference over [lo, hi): (v of the code with first
+    component f, found); the fixtures hold each f once."""
+    for i in range(lo, hi):
+        fi, vi = _unpair_py(int(codes[i]))
+        if fi == f:
+            return vi, True
+    return 0, False
+
+
+@pytest.mark.parametrize("window", ["heads", "clamped"])
 @pytest.mark.parametrize("n_codes,n_queries,k", [(1024, 32, 4), (4096, 64, 6)])
-def test_range_search_kernel(n_codes, n_queries, k):
+def test_range_search_kernel(n_codes, n_queries, k, window):
     # f > v throughout: mirrors real per-vertex segments where the candidate
     # window is bounded by the segment size (K chunks). Codes with v > f land
     # near v^2 — the paper's output-sensitive k-term; the wrapper searches
@@ -120,14 +139,63 @@ def test_range_search_kernel(n_codes, n_queries, k):
     packed, widths, ahi, alo = ops.delta_pack(hi, lo)
     sel = rng.choice(n_codes, size=n_queries, replace=False)
     fq, vq = pairing.szudzik_unpair(jnp.asarray(codes[sel]))
-    lbh, lbl = pairing.split_u64(pairing.szudzik_pair(fq, jnp.zeros_like(fq)))
-    cfh, cfl = pairing.split_u64(jnp.asarray(chunks[:, 0]))
-    cidx = ops.candidate_chunks(cfh, cfl, lbh, lbl, k=k)
-    v_out, found = ops.find_next_packed(packed, widths, ahi, alo, cidx,
-                                        fq.astype(U32), interpret=True)
-    assert bool(found.all())
-    np.testing.assert_array_equal(np.asarray(v_out),
-                                  np.asarray(vq).astype(np.uint32))
+    if window == "heads":
+        lbh, lbl = pairing.split_u64(
+            pairing.szudzik_pair(fq, jnp.zeros_like(fq)))
+        cfh, cfl = pairing.split_u64(jnp.asarray(chunks[:, 0]))
+        cidx = ops.candidate_chunks(cfh, cfl, lbh, lbl, k=k)
+        v_out, found = ops.find_next_packed(packed, widths, ahi, alo, cidx,
+                                            fq.astype(U32), interpret=True)
+        assert bool(found.all())
+        np.testing.assert_array_equal(np.asarray(v_out),
+                                      np.asarray(vq).astype(np.uint32))
+        return
+
+    # the store's clamped windows over explicit candidate ranges [lo, hi):
+    # each selected code's own range (inside one chunk); a range over the
+    # two codes either side of a chunk boundary (a block boundary when the
+    # store has a second 8-chunk block), hit in either chunk; and misses
+    # (hi == lo) for an absent f
+    fq = [int(x) for x in np.asarray(fq)]
+    pos = np.searchsorted(codes, codes[sel])
+    ranges = [(int(i), int(i) + 1) for i in pos]
+    edge = 8 * CHUNK if n_codes > 8 * CHUNK else CHUNK
+    for i in (edge - 1, edge):
+        fq.append(_unpair_py(int(codes[i]))[0])
+        ranges.append((edge - 1, edge + 1))
+    absent = int(f.max()) + 1
+    lb = int(pairing.szudzik_pair(jnp.asarray(absent, jnp.uint64),
+                                  jnp.asarray(0, jnp.uint64)))
+    gap = int(np.searchsorted(codes, lb))
+    fq += [absent, absent]
+    ranges += [(gap, gap), (n_codes // 2, n_codes // 2)]
+    lo_r = np.asarray([r[0] for r in ranges])
+    hi_r = np.asarray([r[1] for r in ranges])
+    c0 = lo_r // CHUNK
+    c1 = np.maximum(hi_r - 1, lo_r) // CHUNK
+    n_chunks = chunks.shape[0]
+    steps = c0[:, None] + np.arange(k)[None]
+    clamped = np.clip(np.minimum(steps, c1[:, None]), 0, n_chunks - 1)
+    fixed = np.clip(steps, 0, n_chunks - 1)
+    assert (clamped[-4:-2] != clamped[-4:-2, :1]).any(axis=1).all()
+    assert edge == CHUNK or (c0[-4] // 8 != c1[-4] // 8)
+
+    def search(cidx):
+        out, hit = ops.find_next_packed(
+            packed, widths, ahi, alo, jnp.asarray(cidx, jnp.int32),
+            jnp.asarray(fq, U32), interpret=True)
+        return np.asarray(out), np.asarray(hit)
+
+    v_out, found = search(clamped)
+    want = [_scan_range_py(codes, a, b, fx)
+            for a, b, fx in zip(lo_r, hi_r, fq)]
+    np.testing.assert_array_equal(found, [w[1] for w in want])
+    np.testing.assert_array_equal(v_out[found],
+                                  [w[0] for w in want if w[1]])
+    assert found[:-2].all() and not found[-2:].any()
+    v_fix, found_fix = search(fixed)
+    np.testing.assert_array_equal(v_fix[found], v_out[found])
+    assert found_fix[found].all()
 
 
 def test_range_search_miss():

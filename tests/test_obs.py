@@ -407,6 +407,55 @@ def test_deg_fallback_counter_replay():
     assert want > 0, "fixture too sparse to exercise the deg>dmax fallback"
 
 
+def _szudzik_py(a: int, b: int) -> int:
+    return b * b + a if a < b else a * a + a + b
+
+
+def test_findnext_counters_replay():
+    """Order-2 stream, ONE batch from a fresh corpus (so the traversal
+    reads the initial corpus): findnext_queries counts every lane's l-1
+    prefix FINDNEXTs, and findnext_chunks equals the numpy sum over them of
+    c1 - c0 + 1, the chunks each query's candidate range [lo, hi) spans in
+    the initial store. With metrics OFF no obs_metrics op is traced."""
+    model = WalkModel(order=2, p=0.5, q=2.0, sampler="factorized", dmax=4)
+    cfg = WalkConfig(n_walks_per_vertex=2, length=8, model=model)
+    g, store = make_graph_store(cfg)
+    i_s, i_d, d_s, d_d = make_stream(n_batches=1)
+    keys = jax.random.split(jax.random.PRNGKey(3), 1)
+    state = EngineState.create(g, store, MAX_PENDING, CAP * cfg.length)
+    off = _run_stream_jit.lower(
+        state, keys, i_s, i_d, d_s, d_d, cfg=cfg, capacity=CAP,
+        mav_capacity=store.size, max_pending=MAX_PENDING,
+        merge_policy="on-demand", merge_impl="interleave").as_text()
+    assert "obs_metrics" not in off
+
+    walks = np.asarray(make_engine(g, store, cfg, "on-demand").walk_matrix())
+    eng = make_engine(g, store, cfg._replace(metrics=True), "on-demand")
+    _, aux = eng.run_stream(jax.random.PRNGKey(3), i_s, i_d, d_s, d_d,
+                            return_masks=True)
+    code = np.asarray(store.code).astype(object)
+    offsets = np.asarray(store.offsets)
+    vmin, vmax = np.asarray(store.vmin), np.asarray(store.vmax)
+    chunk, length = 128, cfg.length
+    want_q = want_c = 0
+    for w in np.asarray(aux.walk_ids[0]):
+        for p in range(length - 1):
+            v = walks[w, p]
+            f = int(w) * length + p
+            seg = list(code[offsets[v]:offsets[v + 1]])
+            lo = offsets[v] + int(np.searchsorted(
+                seg, _szudzik_py(f, int(vmin[v])), side="left"))
+            hi = offsets[v] + int(np.searchsorted(
+                seg, _szudzik_py(f, int(vmax[v])), side="right"))
+            c0, c1 = lo // chunk, max(hi - 1, lo) // chunk
+            want_q += 1
+            want_c += c1 - c0 + 1
+    got = summary(eng.metrics)["order2"]
+    assert got["findnext_queries"] == want_q == CAP * (length - 1)
+    assert got["findnext_chunks"] == want_c
+    assert want_q <= want_c < 2 * want_q
+
+
 # ------------------------------------------------------------ (b) sharded
 
 

@@ -10,6 +10,7 @@ import pytest
 from repro.core import StreamingGraph, WalkConfig, generate_corpus, pairing
 from repro.core import packed_store
 from repro.core.packed_store import CHUNK
+from repro.core.store import WalkStore
 from repro.core.update import WalkEngine
 from repro.data.streams import rmat_edges
 from repro.kernels import ops
@@ -126,17 +127,89 @@ def test_traverse_backends_equivalent():
 
 
 def test_small_window_falls_back_exactly():
-    """A 1-chunk kernel window forces the overflow fallback for candidate
-    ranges crossing a chunk boundary — results must still match the
+    """A 1- or 2-chunk kernel window forces the overflow fallback for
+    candidate ranges wider than it — results must still match the
     reference exactly."""
     eng = make_engine(n_w=4, length=10)
     stream(eng, n_batches=2)
     v, w, p, _ = queries_from(eng, n=16)
     s = eng.store
     ref = s.find_next(v, w, p, backend="xla-ref")
-    got = s.find_next(v, w, p, backend="pallas-interpret", window=1)
-    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
-    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+    for window in (1, 2):
+        got = s.find_next(v, w, p, backend="pallas-interpret", window=window)
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+
+
+def test_clamped_window_matches_ref_mid_update():
+    """Mid-update (pending blocks live, slot_epoch bumped), over a store of
+    several 8-chunk blocks: the kernel over the windows clamped to each
+    range's chunks answers find_next and traverse, through the base store
+    and through the overlay, exactly as xla-ref."""
+    eng = make_engine(n_w=4, length=10)
+    v, w, p, _ = queries_from(eng)   # corpus positions BEFORE the updates
+    stream(eng, n_batches=2)
+    assert eng.n_pending > 0
+    s = eng.store
+    assert s.n_chunks > 2 * 8
+    for view in (s, eng.overlay()):
+        ref = view.find_next(v, w, p, backend="xla-ref")
+        got = view.find_next(v, w, p, backend="pallas-interpret")
+        for a, b in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    walks = jnp.arange(s.n_walks, dtype=U32)
+    start = (walks // eng.cfg.n_walks_per_vertex).astype(U32)
+    for view in (s, eng.overlay()):
+        a = view.traverse(walks, start, s.length - 1,
+                          backend="pallas-interpret")
+        b = view.traverse(walks, start, s.length - 1, backend="xla-ref")
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the clamped windows decode only the chunks each range spans
+    n = np.asarray(s.findnext_chunks(v, w, p))
+    assert (n >= 1).all() and (n <= 2).all()
+
+
+def test_wide_ranges_decode_their_chunks_exactly():
+    """Segments whose candidate ranges span many chunks (small f, every
+    next vertex above f): ranges of 8 chunks inside one block and across a
+    block boundary, and of 2 chunks. The kernel answers as xla-ref with the
+    default window and with a 2-chunk one (the wider ranges fall back), and
+    findnext_chunks counts the chunks each range spans, capped at the
+    window."""
+    length, n_walks = 8, 256
+    t = n_walks * length
+    f = np.arange(t)
+    owner = np.where(f < 1000, 0, np.where(f < 1800, 1, 2)).astype(np.uint32)
+    nxt = (t + (f * 37) % 1024).astype(np.uint64)
+    code = pairing.szudzik_pair(jnp.asarray(f, jnp.uint64), jnp.asarray(nxt))
+    s = WalkStore.build(jnp.asarray(owner), code, jnp.zeros(t, U32),
+                        jnp.zeros(t, U32), length, n_walks, 3)
+    rng = np.random.default_rng(0)
+    q = np.concatenate([rng.choice(np.nonzero(owner == o)[0], 12,
+                                   replace=False) for o in range(3)])
+    v = jnp.asarray(np.concatenate([owner[q[:-4]], (owner[q[-4:]] + 1) % 3]),
+                    U32)
+    w, p = jnp.asarray(q // length, U32), jnp.asarray(q % length, U32)
+    ref = s.find_next(v, w, p, backend="xla-ref")
+    for window in (None, 2):
+        got = s.find_next(v, w, p, backend="pallas-interpret", window=window)
+        for a, b in zip(got, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.asarray(ref[1])[:-4].all()
+
+    _, lo, hi, _, _ = s._candidate_range(
+        v, jnp.asarray(w, jnp.uint64), jnp.asarray(p, jnp.uint64))
+    lo, hi = np.asarray(lo), np.asarray(hi)
+    c0, c1 = lo // CHUNK, np.maximum(hi - 1, lo) // CHUNK
+    assert ((c1 - c0 + 1)[:12] == 8).all() and (c1[:12] < 8).all()
+    assert ((c1 - c0 + 1)[12:24] == 8).all() and (c0[12:24] < 8).all() \
+        and (c1[12:24] >= 8).all()
+    assert (c1 - c0 + 1)[24:32].max() > 1
+    assert np.asarray(s.offsets).tolist() == [0, 1000, 1800, t]
+    for window in (8, 2):
+        want = np.minimum(c1, c0 + window - 1) - c0 + 1
+        np.testing.assert_array_equal(
+            np.asarray(s.findnext_chunks(v, w, p, window=window)), want)
 
 
 def test_interpret_matches_pallas_interpret_kernel():

@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 import chip_smoke as smoke
 import repro.core  # noqa: F401  (x64 on, as the engine runs)
+from repro.core.store import WalkStore
 from repro.kernels import (delta, intersect, megakernel, range_search, sgns,
                            szudzik)
 
@@ -107,6 +108,31 @@ def test_named_findnext_kernel_is_found_by_the_benchmark(compile_for):
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == 1 and calls[0].startswith("%wharf_findnext")
     assert roofline.findnext_queries(calls[0]) == CELL_SLAB == 256
+
+
+def test_store_find_next_compiles_at_the_cell_size(compile_for, monkeypatch):
+    """WalkStore.find_next on TPU in one program: each query's window
+    clamped to the chunks its candidate range spans, the kernel, the
+    verification and the over-window fallback, at node2vec-er's store and
+    the cell's slab."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, length = 1 << 10, 80
+    t = CELL_C * delta.CHUNK
+
+    def find_next(owner, code, epoch, offsets, vmin, vmax, packed, widths,
+                  a_hi, a_lo, l_hi, l_lo, slot_epoch, v, w, p):
+        s = WalkStore(owner, code, epoch, offsets, vmin, vmax, packed,
+                      widths, a_hi, a_lo, l_hi, l_lo, slot_epoch,
+                      length=length, n_walks=t // length, n_vertices=n,
+                      chunk_b=delta.CHUNK)
+        return s.find_next(v, w, p, backend="pallas")
+
+    chunk_meta = [((CELL_C,), U32)] * 5
+    text = compile_for(find_next, ((t,), U32), ((t,), jnp.uint64),
+                       ((t,), U32), ((n + 1,), I32), ((n,), U32), ((n,), U32),
+                       ((CELL_C, delta.WORDS), U32), *chunk_meta,
+                       ((t,), U32), *[((CELL_SLAB,), U32)] * 3)
+    assert "wharf_findnext" in text
 
 
 def test_intersect_compiles_at_dmax_128(compile_for):
